@@ -742,30 +742,6 @@ def scale_bench(out_path, quick=False):
               f"ofrac={p['overlap_fraction']}")
     efficiency(strong_points, weak=False)
 
-    # bit-identity cross-check: memmap-streamed sharded diagram vs the
-    # in-memory single-device diagram, full pipeline
-    check_dims = (32, 32, 32) if quick else (64, 64, 64)
-    g = Grid.of(*check_dims)
-    f = make_field("wavelet", check_dims, seed=0)
-    pipe = PersistencePipeline(backend="jax")
-    ref = pipe.diagram(f, grid=g)
-    with tempfile.TemporaryDirectory() as td:
-        src = MemmapSource.write(os.path.join(td, "f.raw"),
-                                 f.reshape(check_dims[::-1]))
-        res = pipe.run(TopoRequest(field=src, stream=True, chunk_z=chunk_z,
-                                   n_blocks=4))
-    assert same_offdiagonal(res.diagram, ref.diagram), \
-        diff_report(res.diagram, ref.diagram)
-    for p in range(g.dim + 1):
-        assert np.array_equal(res.diagram.essential_orders(p),
-                              ref.diagram.essential_orders(p))
-    bit_identity = {
-        "dims": list(check_dims), "n_shards": int(res.stream.n_shards),
-        "source": "memmap", "checked": True,
-        "peak_resident_field_bytes":
-            int(res.stream.peak_resident_field_bytes)}
-    print(f"  bit-identity {check_dims} x{res.stream.n_shards} memmap: OK")
-
     # full mode: one >= 256^3 memmap-streamed sharded run — the field
     # file exists on disk only; each shard keeps ~2 ghost-extended
     # chunks resident
@@ -791,6 +767,32 @@ def scale_bench(out_path, quick=False):
         print(f"  memmap {big} x4: wall={memmap_large['wall_s']:.1f}s "
               f"resident={fmt_bytes(memmap_large['peak_resident_field_bytes'])}"
               f" of {fmt_bytes(field_bytes)}")
+
+    # bit-identity cross-check: memmap-streamed sharded diagram vs the
+    # in-memory single-device diagram, full pipeline.  It runs in this
+    # process, so it comes after every worker subprocess: a parent that
+    # has touched JAX holds the accelerator its children would need
+    check_dims = (32, 32, 32) if quick else (64, 64, 64)
+    g = Grid.of(*check_dims)
+    f = make_field("wavelet", check_dims, seed=0)
+    pipe = PersistencePipeline(backend="jax")
+    ref = pipe.diagram(f, grid=g)
+    with tempfile.TemporaryDirectory() as td:
+        src = MemmapSource.write(os.path.join(td, "f.raw"),
+                                 f.reshape(check_dims[::-1]))
+        res = pipe.run(TopoRequest(field=src, stream=True, chunk_z=chunk_z,
+                                   n_blocks=4))
+    assert same_offdiagonal(res.diagram, ref.diagram), \
+        diff_report(res.diagram, ref.diagram)
+    for p in range(g.dim + 1):
+        assert np.array_equal(res.diagram.essential_orders(p),
+                              ref.diagram.essential_orders(p))
+    bit_identity = {
+        "dims": list(check_dims), "n_shards": int(res.stream.n_shards),
+        "source": "memmap", "checked": True,
+        "peak_resident_field_bytes":
+            int(res.stream.peak_resident_field_bytes)}
+    print(f"  bit-identity {check_dims} x{res.stream.n_shards} memmap: OK")
 
     doc = bench_doc(
         "ddms-scale-bench/v1", quick=quick,
@@ -1299,6 +1301,8 @@ def main():
                     help="Perfetto trace path for --section obs "
                          "(default <out>_trace.trace.json)")
     args = ap.parse_args()
+    from repro.compile_cache import use_checkout_cache
+    use_checkout_cache(Path(__file__).resolve().parents[1])
     if args.section == "pipeline":
         pipeline_bench(args.out or "BENCH_pipeline.json")
         return

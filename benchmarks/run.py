@@ -27,6 +27,7 @@ wall clock).
 
 import argparse
 import time
+from pathlib import Path
 
 import numpy as np
 
@@ -215,6 +216,8 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="small-size subset for CI smoke")
     args = ap.parse_args()
+    from repro.compile_cache import use_checkout_cache
+    use_checkout_cache(Path(__file__).resolve().parents[1])
     print("name,us_per_call,derived")
     if args.quick:
         fig12_step_breakdown(QUICK_DIMS)

@@ -26,9 +26,11 @@ def main():
 
     import time
 
+    from repro.compile_cache import use_checkout_cache
     from repro.stream import (FunctionSource, MemmapSource,
                               sharded_stream_front)
 
+    use_checkout_cache(os.path.join(os.path.dirname(__file__), ".."))
     dims = tuple(int(d) for d in spec["dims"])
     if spec.get("memmap"):
         src = MemmapSource(spec["memmap"], dims)
@@ -43,11 +45,11 @@ def main():
 
     if spec.get("warm", True):
         # compile every chunk shape out of the timed run
-        sharded_stream_front(src, n, kernel="jax", **kw)
+        sharded_stream_front(src, n, **kw)
     best = None
     for _ in range(int(spec.get("reps", 1))):
         t0 = time.perf_counter()
-        out = sharded_stream_front(src, n, kernel="jax", **kw)
+        out = sharded_stream_front(src, n, **kw)
         dt = time.perf_counter() - t0
         if best is None or dt < best[0]:
             best = (dt, out.report)

@@ -132,21 +132,13 @@ class FrontConfig:
 
 # -- mesh-axis helpers (single name or tuple; z is split over all of them) --
 
-def _one_axis_size(a) -> int:
-    # jax.lax.axis_size only exists in newer jax; fall back to the static
-    # axis env (jax.core.axis_frame returns the int size on 0.4.x)
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return int(jax.core.axis_frame(a))
-
-
 def _axis_size(ax):
     if isinstance(ax, tuple):
         n = 1
         for a in ax:
-            n *= _one_axis_size(a)
+            n *= jax.lax.axis_size(a)
         return n
-    return _one_axis_size(ax)
+    return jax.lax.axis_size(ax)
 
 
 def _axis_index(ax):
@@ -369,14 +361,12 @@ def halo_gradient(cfg: FrontConfig, ranks):
 def _gradient_rows(cfg: FrontConfig, nbrs, ov, ext=None):
     rb = _rank_bound(cfg)
     if cfg.gradient_backend == "fused" and ext is not None:
-        return fused_rows_from_halo_volume(ext, interpret=True,
-                                           rank_bound=rb)
+        return fused_rows_from_halo_volume(ext, rank_bound=rb)
     if rb is not None and rb < 2 ** 31:
         nbrs = nbrs.astype(jnp.int32)
         ov = ov.astype(jnp.int32)
     if cfg.gradient_backend == "pallas":
-        return lower_star_gradient_pallas(nbrs, ov, interpret=True,
-                                          rank_bound=rb)
+        return lower_star_gradient_pallas(nbrs, ov, rank_bound=rb)
     if cfg.gradient_chunk is None:
         return REF.lower_star_gradient_jnp(nbrs, ov, rank_bound=rb)
     n = nbrs.shape[0]
@@ -623,7 +613,6 @@ def run_front(dims, f, n_blocks: int, mesh=None, **cfg_kw):
     """Execute the front-end under shard_map on ``n_blocks`` devices.
     Returns numpy outputs (triplet buffers, ranks, stats)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     cfg = FrontConfig(tuple(dims), n_blocks, axis_name="blocks", **cfg_kw)
     cfg.nz_local  # eager divisibility check: fail with dims/blocks named
@@ -633,8 +622,8 @@ def run_front(dims, f, n_blocks: int, mesh=None, **cfg_kw):
     def dev_fn(f_slab):
         return front_device_fn(cfg, f_slab)
 
-    fn = shard_map(dev_fn, mesh=mesh, in_specs=P("blocks"),
-                   out_specs=_front_out_specs(), check_rep=False)
+    fn = jax.shard_map(dev_fn, mesh=mesh, in_specs=P("blocks"),
+                       out_specs=_front_out_specs(), check_vma=False)
     out = jax.jit(fn)(jnp.asarray(np.asarray(f).reshape(-1), jnp.float32))
     out = {k: np.asarray(v) for k, v in out.items()}
     peak = int(out["crit_peak"])

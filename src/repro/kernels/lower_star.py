@@ -1,37 +1,43 @@
 """Pallas TPU kernels for the lower-star discrete gradient.
 
-TARGET: TPU v5e.  Two kernels share one branchless pairing core
-(:func:`_pair_block`, the ProcessLowerStars masked-recomputation form —
-priority queues become masked lexicographic argmins, scatter-style updates
-become one-hot selects):
+TARGET: TPU v5e, compiled with Mosaic; on other platforms the same kernels
+run in Pallas interpret mode (:func:`interpret_mode` decides, from the
+platform alone).
 
-1. **Fused halo-aware kernel** (:func:`fused_lower_star_gradient_pallas`) —
-   the production front-end.  The padded 3-D order volume is tiled with
-   *halo-overlapping* BlockSpecs (``pl.Unblocked`` element indexing): the
-   grid is (batch, z-slabs, y-tiles) and each block reads a
-   ``(tile_z+2, tile_y+2, nx+2)`` window (one-vertex halo on every side)
-   straight from HBM, builds its ``(tile_z*tile_y*nx, 27)`` neighbor table
-   *in VMEM* with 27 static shifted slices, and pairs on-chip.  The order
-   field is read ~once (halo overlap adds a few percent) — ~4 B/vertex of
-   HBM traffic on the int32 rank path instead of the 216 B/vertex the
-   materialized int64 im2col pre-pass used to move.  Outputs are int8
-   status/partner (74 packed rows fit int8), another ~4x off the write
-   traffic.  A leading batch grid dimension serves
-   ``PersistencePipeline.diagrams`` batches in a single dispatch.
+Layout: vertices on lanes.  A block of vertices is an ``(8, lanes)``
+int32 tile, and the 74 packed star rows stack on a leading axis,
+``(74, 8, lanes)``.  The neighbor table is static, so the 222 neighbor
+selections stack tile-shaped planes and never gather; the face table
+enters as a constant input, and the unpaired-face counts are kept
+incrementally, so no step gathers rows by a table either.  Priority-
+queue pops are masked lexicographic argmins over the row axis (three
+column passes of min — no int64 packed keys inside a kernel), and
+scatter-style updates are selects.  Both kernels share this core
+(:func:`_pair_planes`) and write their results as 19 int32 words per
+vertex: 4 row bytes per word, the 74 row bytes followed by the vertex
+byte (``vstat`` in the status words, ``vpart`` in the partner words).
+:func:`host_rows` (or, inside a device program, :func:`_device_rows`)
+turns the words back into the (n, 74) int8 rows.
 
-2. **Pre-pass kernel** (:func:`lower_star_gradient_pallas`) — the original
-   im2col-style path kept as a fallback and as the oracle cross-check: the
-   stencil gather happens outside as a ``(n, 27)`` tensor and the kernel
-   tiles the vertex axis only.  Inputs are *bucket-padded* to power-of-two
-   multiples of the tile so distinct lengths within one bucket share a
-   compiled program (see :func:`bucket_len`; probe compile reuse via
-   ``prepass_cache_size``).
+1. **Fused kernel** (:func:`fused_lower_star_gradient_pallas`) — the
+   production front-end.  The grid is (batch, z-plane, y-tile).  Each step
+   reads the 3 z-planes x 3 y-tiles around its tile (plain blocked
+   BlockSpecs; the z halo planes are part of the input volume, so the
+   shardmap front's ring-exchanged ghost planes feed it directly), builds
+   the 27 neighbor planes in VMEM with sublane/lane rolls masked at the
+   grid boundary, then pairs 128 lanes at a time.  No (nv, 27) tensor
+   ever touches HBM.
 
-Working set per fused block (tile_z=4, tile_y=8, nx=128): 4 KB window +
-128 KB nbrs + 1.5 MB packed int64 keys + masks — comfortably inside the
-16 MB VMEM with room for double buffering.  Validated in ``interpret=True``
-mode on CPU against ``ref.py`` (which is in turn validated against the
-literal priority-queue reference).
+2. **Pre-pass kernel** (:func:`lower_star_gradient_pallas`) — the im2col
+   path kept as a fallback and oracle cross-check: the stencil gather
+   happens outside as an (n, 27) tensor.  Inputs are *bucket-padded* to
+   power-of-two multiples of the tile so distinct lengths within one
+   bucket share a compiled program (see :func:`bucket_len`; probe compile
+   reuse via ``prepass_cache_size``).
+
+Ranks enter the kernels as int32; the wrappers narrow int64 ranks given a
+``rank_bound`` below 2**31 and refuse 64-bit keys they cannot narrow (the
+rank-free streaming keys run on the XLA ``jax`` kernel instead).
 """
 
 from __future__ import annotations
@@ -42,87 +48,174 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import gradient as GR
 from repro.core import grid as G
-from . import ref as REF
 
-R = REF.R
-EDGE_ROWS = REF.EDGE_ROWS
+R = GR.NROWS                        # 74 packed star rows
+EDGE_ROWS = G.NSTAR[1]              # rows [0, 14) are edges
+TET_ROWS = GR.ROW_OFF[3]            # rows [50, 74) are tetrahedra
 NOT_L, AVAIL, TAIL, HEAD, CRIT = (GR.NOT_L, GR.AVAIL, GR.TAIL, GR.HEAD,
                                   GR.CRIT)
+# static star table: the neighbor indices of each row's other vertices
+_OTH = tuple(tuple(int(o) for o in row if o >= 0)
+             for row in GR.PACKED["others"])
+WORDS = -(-(R + 1) // 4)            # 19 int32 words: 74 row bytes + 1
+SUBLANES, LANES = 8, 128
+_INF = int(np.iinfo(np.int32).max)
+_I32 = np.int32                      # explicit: x64 is on process-wide
 
 
-_onehot_set = REF.onehot_set
+def interpret_mode() -> bool:
+    """Pallas kernels compile with Mosaic on a TPU and are interpreted
+    on every other platform."""
+    return jax.default_backend() != "tpu"
 
 
-def _pair_block(nbrs, ov, oth, fid, packed: bool):
-    """Branchless ProcessLowerStars over one block of vertices.
+def _as_int32(x, rank_bound):
+    """int32 ranks for a kernel: int32 passes, 64-bit ranks narrow when
+    ``rank_bound`` proves they fit, anything else is refused."""
+    if x.dtype == jnp.int32:
+        return x
+    if rank_bound is not None and int(rank_bound) < 2 ** 31:
+        return x.astype(jnp.int32)
+    raise TypeError(
+        f"Pallas lower-star kernels take int32 ranks; got {x.dtype} with "
+        f"rank_bound={rank_bound}.  Pass a rank_bound < 2**31, or run "
+        "rank-free 64-bit keys on the 'jax' kernel")
 
-    nbrs (n, 27), ov (n,): neighbor/vertex orders (-1 outside the grid).
-    Returns (status (n,R) int8, partner (n,R) int8, vstat (n,) int8,
-    vpart (n,) int32).  ``packed`` selects the single-word int64 key path
-    (valid when ranks < 2**21); both paths are bit-identical.
-    """
-    n = nbrs.shape[0]
-    idt = nbrs.dtype
-    inf = jnp.asarray(np.iinfo(np.dtype(idt.name)).max, idt)
 
-    vals = jnp.where(oth >= 0, nbrs[:, jnp.maximum(oth, 0)],
-                     jnp.asarray(-1, idt))
-    real = oth >= 0
-    in_l = (((~real) | (vals >= 0)) & ((~real) | (vals < ov[:, None, None]))
-            ).all(-1)
-    keys = REF.sort3_desc(vals)
-    if packed:
-        pkeys = REF.pack_key3(keys)
+# --------------------------------------------------------------------------
+# the shared pairing core: star rows stacked on the leading axis
+# --------------------------------------------------------------------------
 
-    def pop(mask):
-        if packed:
-            return REF.lexmin_packed(pkeys, mask)
-        return REF.lexmin(keys, mask, inf), mask.any(-1)
+def fid_planes(shape):
+    """The face table as (3, R) + shape int32 planes (-1 padded): the
+    kernels' only constant input (kernels may not capture arrays)."""
+    fid = np.asarray(GR.PACKED["fid"], np.int32).T             # (3, R)
+    return jnp.broadcast_to(jnp.asarray(fid).reshape(fid.shape + (1,) * len(
+        shape)), fid.shape + tuple(shape))
 
-    status = jnp.where(in_l, jnp.int8(AVAIL), jnp.int8(NOT_L))  # (n,R)
-    partner = jnp.full((n, R), -1, jnp.int8)
-    rows = jnp.arange(R)
 
-    delta, has_edge = pop((status == AVAIL) & (rows[None, :] < EDGE_ROWS))
-    vstat = jnp.where(has_edge, jnp.int8(TAIL), jnp.int8(CRIT))
-    vpart = jnp.where(has_edge, delta, -1).astype(jnp.int32)
-    status = _onehot_set(status, delta, HEAD, has_edge)
-    partner = _onehot_set(partner, delta, -2, has_edge)
+def _sort3_desc(a, b, c):
+    a, b = jnp.maximum(a, b), jnp.minimum(a, b)
+    a, c = jnp.maximum(a, c), jnp.minimum(a, c)
+    b, c = jnp.maximum(b, c), jnp.minimum(b, c)
+    return a, b, c
 
-    def cond(carry):
-        return ~carry[2].all()
+
+def _lexmin(mask, keys, rid):
+    """(row, any) of the lexicographically smallest key row under
+    ``mask`` (R, ...); the row is R where the mask is empty."""
+    m = mask
+    for kc in keys:
+        v = jnp.where(m, kc, _I32(_INF))
+        m = m & (v == v.min(axis=0, keepdims=True))
+    idx = jnp.where(m, rid, _I32(R)).min(axis=0)
+    return idx, idx < R
+
+
+def _pair_planes(nb, ov, fid):
+    """Branchless ProcessLowerStars over one tile of vertices.
+
+    nb: 27 int32 neighbor-order planes (-1 outside the grid), ov: the
+    vertex-order plane, fid: :func:`fid_planes` of the tile shape.
+    Returns (status, partner) as (R,) + tile int32 and (vstat, vpart)
+    planes — the same values as ``ref.lower_star_gradient_jnp``.
+
+    The unpaired-face counts are kept incrementally: every in-star row
+    starts with all of its faces available (0 / 2 / 3 for edges /
+    triangles / tets), and each row leaving AVAIL decrements the count
+    of the rows it is a face of, so no step gathers rows by a table."""
+    neg = jnp.full(ov.shape, -1, jnp.int32)
+    vals = [jnp.stack([nb[o[m]] if m < len(o) else neg for o in _OTH])
+            for m in range(3)]                                  # 3 x (R,..)
+    rid = jax.lax.broadcasted_iota(jnp.int32, vals[0].shape, 0)
+    lower = [(v >= 0) & (v < ov) for v in vals]
+    in_l = lower[0] & (lower[1] | (rid < EDGE_ROWS)) \
+        & (lower[2] | (rid < TET_ROWS))
+    keys = _sort3_desc(*vals)
+    status = jnp.where(in_l, _I32(AVAIL), _I32(NOT_L))
+    partner = jnp.full(status.shape, -1, jnp.int32)
+    nuf = jnp.where(rid < EDGE_ROWS, _I32(0),
+                    jnp.where(rid < TET_ROWS, _I32(2), _I32(3)))
+
+    def cofaces_of(row):
+        """(R, ...) 1 where ``row`` (one per vertex) is a face of r."""
+        return ((fid[0] == row) | (fid[1] == row) | (fid[2] == row)
+                ).astype(jnp.int32)
+
+    delta, has_edge = _lexmin(in_l & (rid < EDGE_ROWS), keys, rid)
+    vstat = jnp.where(has_edge, _I32(TAIL), _I32(CRIT))
+    vpart = jnp.where(has_edge, delta, _I32(-1))
+    hit = rid == delta
+    status = jnp.where(hit, _I32(HEAD), status)
+    partner = jnp.where(hit, _I32(-2), partner)
+    nuf = nuf - cofaces_of(delta)
 
     def body(carry):
-        status, partner, _ = carry
+        status, partner, nuf, _ = carry
         avail = status == AVAIL
-        fa = (fid >= 0) & avail[:, jnp.maximum(fid, 0)]
-        nuf = fa.sum(-1, dtype=jnp.int8)
-        alpha, any1 = pop(avail & (nuf == 1))
-        fa_a = jnp.take_along_axis(fa, alpha[:, None, None], axis=1)[:, 0]
-        fid_a = fid[alpha]
-        face = jnp.take_along_axis(
-            fid_a, jnp.argmax(fa_a, -1)[:, None], axis=-1)[:, 0]
-        gamma, any0 = pop(avail & (nuf == 0))
-        do1 = any1
-        do0 = (~any1) & any0
-        status = _onehot_set(status, alpha, HEAD, do1)
-        status = _onehot_set(status, face, TAIL, do1)
-        status = _onehot_set(status, gamma, CRIT, do0)
-        partner = jnp.where(
-            ((rows[None, :] == alpha[:, None]) & do1[:, None]),
-            face[:, None].astype(jnp.int8), partner)
-        partner = jnp.where(
-            ((rows[None, :] == face[:, None]) & do1[:, None]),
-            alpha[:, None].astype(jnp.int8), partner)
-        done = ~(any1 | any0)
-        return status, partner, done
+        alpha, any1 = _lexmin(avail & (nuf == 1), keys, rid)
+        gamma, any0 = _lexmin(avail & (nuf == 0), keys, rid)
+        # alpha's one available face: its first face row still AVAIL
+        is_a = rid == alpha
+        f = [jnp.where(is_a, fid[m], _I32(-1)).max(axis=0) for m in range(3)]
+        ok = [jnp.where(avail & (rid == f_m), _I32(1), _I32(0)).max(axis=0)
+              > 0 for f_m in f]
+        face = jnp.where(ok[0], f[0], jnp.where(ok[1], f[1],
+                                                jnp.where(ok[2], f[2], f[0])))
+        do0 = ~any1 & any0
+        is_a = is_a & any1
+        is_f = (rid == face) & any1
+        is_g = (rid == gamma) & do0
+        status = jnp.where(is_a, _I32(HEAD), status)
+        status = jnp.where(is_f, _I32(TAIL), status)
+        status = jnp.where(is_g, _I32(CRIT), status)
+        partner = jnp.where(is_a, face, partner)
+        partner = jnp.where(is_f, alpha, partner)
+        nuf = nuf - jnp.where(any1, cofaces_of(alpha) + cofaces_of(face),
+                              jnp.where(do0, cofaces_of(gamma), _I32(0)))
+        go = jnp.max((any1 | any0).astype(jnp.int32)) > 0
+        return status, partner, nuf, go
 
-    status, partner, _ = jax.lax.while_loop(
-        cond, body, (status, partner, jnp.zeros(n, bool)))
+    status, partner, _, _ = jax.lax.while_loop(
+        lambda c: c[3], body, (status, partner, nuf, jnp.asarray(True)))
     return status, partner, vstat, vpart
+
+
+def _pack(planes):
+    """Row-byte planes (values in int8 range) -> WORDS int32 planes."""
+    planes = planes + [jnp.zeros_like(planes[0])] * (4 * WORDS - len(planes))
+    return [(planes[4 * q] & 0xFF) | ((planes[4 * q + 1] & 0xFF) << 8)
+            | ((planes[4 * q + 2] & 0xFF) << 16) | (planes[4 * q + 3] << 24)
+            for q in range(WORDS)]
+
+
+def _store_rows(st_ref, pt_ref, at, status, partner, vstat, vpart):
+    for ref, rows, v in ((st_ref, status, vstat), (pt_ref, partner, vpart)):
+        for q, w in enumerate(_pack([rows[r] for r in range(R)] + [v])):
+            ref[at(q)] = w
+
+
+def _split_bytes(words, axis: int):
+    """int32 words (word axis at ``axis``) -> their int8 row bytes along
+    that axis, in little-endian order, cut to the R + 1 used bytes."""
+    shift = jnp.asarray([24, 16, 8, 0], jnp.int32).reshape(
+        (4,) + (1,) * (words.ndim - axis - 1))
+    b = ((jnp.expand_dims(words, axis + 1) << shift) >> 24).astype(jnp.int8)
+    b = b.reshape(words.shape[:axis] + (4 * WORDS,) + words.shape[axis + 1:])
+    return jax.lax.slice_in_dim(b, 0, R + 1, axis=axis)
+
+
+def _split_rows(st, pt, xp=jnp):
+    """(n, R + 1) status/partner bytes -> (status, partner, vstat, vpart)."""
+    return st[:, :R], pt[:, :R], st[:, R], pt[:, R].astype(xp.int32)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 # --------------------------------------------------------------------------
@@ -134,62 +227,51 @@ def bucket_len(n: int, tile: int) -> int:
 
     Distinct input lengths that land in one bucket share a compiled
     program; the padding waste is < 2x and the padded lanes retire after
-    the first loop iteration (everything is NOT_L for an order of -1/0)."""
+    the first loop iteration (everything is NOT_L for an order of -1)."""
     b = tile
     while b < n:
         b *= 2
     return b
 
 
-def _maybe_int32(x, rank_bound):
-    if rank_bound is not None and int(rank_bound) < 2 ** 31:
-        return x.astype(jnp.int32)
-    return x
-
-
 # --------------------------------------------------------------------------
 # pre-pass (im2col) kernel — fallback + oracle cross-check
 # --------------------------------------------------------------------------
 
-def _prepass_kernel(nbrs_ref, ov_ref, oth_ref, fid_ref, status_ref,
-                    partner_ref, vstat_ref, vpart_ref, *, packed: bool):
-    nbrs = nbrs_ref[...]          # (TILE, 27)
-    ov = ov_ref[...][:, 0]        # (TILE,)
-    status, partner, vstat, vpart = _pair_block(
-        nbrs, ov, oth_ref[...], fid_ref[...], packed)
-    status_ref[...] = status
-    partner_ref[...] = partner
-    vstat_ref[...] = vstat[:, None]
-    vpart_ref[...] = vpart[:, None]
+def _prepass_kernel(x_ref, fid_ref, st_ref, pt_ref):
+    planes = [x_ref[k] for k in range(28)]      # 27 neighbors + the vertex
+    rows = _pair_planes(planes[:27], planes[27], fid_ref[...])
+    _store_rows(st_ref, pt_ref, lambda q: q, *rows)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret", "packed"))
-def _prepass_call(nbrs, ov, tile: int, interpret: bool, packed: bool):
-    npad = nbrs.shape[0]          # already a tile multiple (bucket-padded)
-    grid_ = (npad // tile,)
-    return pl.pallas_call(
-        functools.partial(_prepass_kernel, packed=packed),
-        grid=grid_,
-        in_specs=[
-            pl.BlockSpec((tile, 27), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((R, 3), lambda i: (0, 0)),
-            pl.BlockSpec((R, 3), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, R), lambda i: (i, 0)),
-            pl.BlockSpec((tile, R), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((npad, R), jnp.int8),
-            jax.ShapeDtypeStruct((npad, R), jnp.int8),
-            jax.ShapeDtypeStruct((npad, 1), jnp.int8),
-            jax.ShapeDtypeStruct((npad, 1), jnp.int32),
-        ],
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _prepass_call(nbrs, ov, *, tile: int, interpret: bool):
+    """nbrs (npad, 27), ov (npad,) int32, npad a multiple of ``tile``.
+
+    A block of ``tile`` vertices is laid out as an (8, tile // 8) tile."""
+    npad = nbrs.shape[0]
+    nblk, t8 = npad // tile, tile // SUBLANES
+    x = jnp.concatenate([nbrs, ov[:, None]], axis=1).T          # (28, npad)
+    x = x.reshape(28, nblk, SUBLANES, t8).transpose(0, 2, 1, 3) \
+        .reshape(28, SUBLANES, nblk * t8)
+    out = pl.BlockSpec((WORDS, SUBLANES, t8), lambda i: (_I32(0), _I32(0), i))
+    shape = jax.ShapeDtypeStruct((WORDS, SUBLANES, nblk * t8), jnp.int32)
+    st, pt = pl.pallas_call(
+        _prepass_kernel,
+        grid=(nblk,),
+        in_specs=[pl.BlockSpec((28, SUBLANES, t8),
+                               lambda i: (_I32(0), _I32(0), i)),
+                  pl.BlockSpec((3, R, SUBLANES, t8),
+                               lambda i: (_I32(0),) * 4)],
+        out_specs=[out, out],
+        out_shape=[shape, shape],
         interpret=interpret,
-    )(nbrs, ov, jnp.asarray(REF.OTH), jnp.asarray(REF.FID))
+    )(x, fid_planes((SUBLANES, t8)))
+
+    def vertex_major(w):            # bytes split in the tile layout
+        return _split_bytes(w, 0).reshape(R + 1, SUBLANES, nblk, t8) \
+            .transpose(2, 1, 3, 0).reshape(npad, R + 1)
+    return _split_rows(vertex_major(st), vertex_major(pt))
 
 
 def prepass_cache_size() -> int:
@@ -197,87 +279,126 @@ def prepass_cache_size() -> int:
     return _prepass_call._cache_size()
 
 
-def lower_star_gradient_pallas(nbrs, ov, tile: int = 256,
-                               interpret: bool = True,
+def lower_star_gradient_pallas(nbrs, ov, tile: int = SUBLANES * LANES,
                                rank_bound: int | None = None):
     """Pallas-tiled lower-star gradient over a pre-gathered im2col tensor.
 
     nbrs (n, 27), ov (n,).  The vertex axis is bucket-padded to a
-    power-of-two multiple of ``tile`` so nearby lengths reuse one compiled
-    program.  ``rank_bound`` (static, = grid.nv) enables the int32 rank
-    and packed-key fast paths.
+    power-of-two multiple of ``tile`` (a multiple of 8; of 1024 on a TPU)
+    so nearby lengths reuse one compiled program.  ``rank_bound``
+    (static, = grid.nv) lets 64-bit ranks narrow to int32.
     """
     n = nbrs.shape[0]
     npad = bucket_len(n, tile)
-    nbrs = _maybe_int32(jnp.asarray(nbrs), rank_bound)
-    ov = _maybe_int32(jnp.asarray(ov), rank_bound)
+    nbrs = _as_int32(jnp.asarray(nbrs), rank_bound)
+    ov = _as_int32(jnp.asarray(ov), rank_bound)
     nbrs_p = jnp.pad(nbrs, ((0, npad - n), (0, 0)), constant_values=-1)
-    ov_p = jnp.pad(ov, (0, npad - n))[:, None]
+    ov_p = jnp.pad(ov, (0, npad - n), constant_values=-1)
     status, partner, vstat, vpart = _prepass_call(
-        nbrs_p, ov_p, tile, interpret, REF.use_packed_keys(rank_bound))
-    return (status[:n], partner[:n], vstat[:n, 0], vpart[:n, 0])
+        nbrs_p, ov_p, tile=tile, interpret=interpret_mode())
+    return status[:n], partner[:n], vstat[:n], vpart[:n]
 
 
 # --------------------------------------------------------------------------
-# fused halo-aware kernel — gather + pairing in one pass over the volume
+# fused kernel — gather + pairing in one pass over the volume
 # --------------------------------------------------------------------------
 
-def _make_fused_kernel(tz: int, ty: int, nx: int, packed: bool):
-    def kernel(vol_ref, oth_ref, fid_ref, status_ref, partner_ref,
-               vstat_ref, vpart_ref):
-        w = vol_ref[...][0]       # (tz+2, ty+2, nx+2) halo-extended window
-        # 27 static shifted slices: the im2col table, built in VMEM.  The
-        # slice index (dz,dy,dx) with dx fastest matches _nbr_index.
-        cols = []
-        for dz in (0, 1, 2):
-            for dy in (0, 1, 2):
-                for dx in (0, 1, 2):
-                    cols.append(w[dz:dz + tz, dy:dy + ty, dx:dx + nx])
-        nbrs = jnp.stack(cols, axis=-1).reshape(tz * ty * nx, 27)
-        ov = w[1:1 + tz, 1:1 + ty, 1:1 + nx].reshape(-1)
-        status, partner, vstat, vpart = _pair_block(
-            nbrs, ov, oth_ref[...], fid_ref[...], packed)
-        status_ref[...] = status.reshape(1, tz, ty, nx, R)
-        partner_ref[...] = partner.reshape(1, tz, ty, nx, R)
-        vstat_ref[...] = vstat.reshape(1, tz, ty, nx)
-        vpart_ref[...] = vpart.reshape(1, tz, ty, nx)
-    return kernel
+def _fused_kernel(*refs, ny: int, nx: int):
+    tiles = [ref[0, 0] for ref in refs[:9]]     # (z+dz, y-tile j+dy)
+    fid_ref, st_ref, pt_ref, nb_ref = refs[9:]
+    ty, nxp = tiles[0].shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (ty, nxp), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (ty, nxp), 1)
+    gy = pl.program_id(2) * _I32(ty) + row
+    neg = _I32(-1)
+
+    def roll(x, shift, axis):
+        return pltpu.roll(x, _I32(shift % x.shape[axis]), axis)
+
+    # the 27 neighbor planes (dx fastest, as _nbr_index), -1 outside
+    for dz in range(3):
+        prev, cur, nxt = tiles[3 * dz: 3 * dz + 3]
+        ys = [jnp.where(row == 0, roll(prev, 1, 0), roll(cur, 1, 0)),
+              cur,
+              jnp.where(row == ty - 1, roll(nxt, -1, 0), roll(cur, -1, 0))]
+        ys[0] = jnp.where(gy == 0, neg, ys[0])
+        ys[2] = jnp.where(gy == ny - 1, neg, ys[2])
+        for dy, p in enumerate(ys):
+            nb_ref[9 * dz + 3 * dy] = jnp.where(lane == 0, neg, roll(p, 1, 1))
+            nb_ref[9 * dz + 3 * dy + 1] = p
+            nb_ref[9 * dz + 3 * dy + 2] = jnp.where(lane == nx - 1, neg,
+                                                    roll(p, -1, 1))
+
+    def chunk(c):
+        lanes = pl.ds(pl.multiple_of(c * _I32(LANES), LANES), LANES)
+        nb = [nb_ref[k, :, lanes] for k in range(27)]
+        rows = _pair_planes(nb, nb[13], fid_ref[...])
+        _store_rows(st_ref, pt_ref, lambda q: (0, 0, q, slice(None), lanes),
+                    *rows)
+        return c + 1
+
+    # an explicit int32 counter: fori_loop's would be int64 under x64
+    jax.lax.while_loop(lambda c: c < nxp // LANES, chunk, _I32(0))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("tile_z", "tile_y", "interpret", "packed"))
-def _fused_call(vol, tile_z: int, tile_y: int, interpret: bool, packed: bool):
-    """vol: (B, nzp+2, nyp+2, nx+2) halo-padded order volume (-1 outside)."""
-    B, nzh, nyh, nxh = vol.shape
-    nzp, nyp, nx = nzh - 2, nyh - 2, nxh - 2
-    tz, ty = tile_z, tile_y
-    grid_ = (B, nzp // tz, nyp // ty)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _fused_call(vol, *, interpret: bool):
+    """vol: (B, nz+2, ny, nx) int32 orders whose first/last z-planes are
+    halo planes (-1 at the global boundary).  Returns the (status,
+    partner) words of the B*nz*ny*nx owned vertices, each (B, nz, 19,
+    ny8, nx128) int32 with y/x padded to the (8, 128) tile."""
+    B, nzh, ny, nx = vol.shape
+    nz = nzh - 2
+    nyp, nxp = _round_up(ny, SUBLANES), _round_up(nx, LANES)
+    nyt = nyp // SUBLANES
+    vol = jnp.pad(vol, ((0, 0), (0, 0), (0, nyp - ny), (0, nxp - nx)),
+                  constant_values=-1)
+
+    def tile(dz, dy):
+        return pl.BlockSpec(
+            (1, 1, SUBLANES, nxp),
+            lambda b, z, j: (b, z + dz, jnp.minimum(
+                jnp.maximum(j + dy, _I32(0)), _I32(nyt - 1)), _I32(0)))
+
+    out = pl.BlockSpec((1, 1, WORDS, SUBLANES, nxp),
+                       lambda b, z, j: (b, z, _I32(0), j, _I32(0)))
+    shape = jax.ShapeDtypeStruct((B, nz, WORDS, nyp, nxp), jnp.int32)
     return pl.pallas_call(
-        _make_fused_kernel(tz, ty, nx, packed),
-        grid=grid_,
-        in_specs=[
-            # halo-overlapping window: element-indexed (Unblocked), each
-            # block reads [i*tz, i*tz+tz+2) x [j*ty, j*ty+ty+2) x all-x
-            pl.BlockSpec((1, tz + 2, ty + 2, nx + 2),
-                         lambda b, i, j: (b, i * tz, j * ty, 0),
-                         indexing_mode=pl.Unblocked()),
-            pl.BlockSpec((R, 3), lambda b, i, j: (0, 0)),
-            pl.BlockSpec((R, 3), lambda b, i, j: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tz, ty, nx, R), lambda b, i, j: (b, i, j, 0, 0)),
-            pl.BlockSpec((1, tz, ty, nx, R), lambda b, i, j: (b, i, j, 0, 0)),
-            pl.BlockSpec((1, tz, ty, nx), lambda b, i, j: (b, i, j, 0)),
-            pl.BlockSpec((1, tz, ty, nx), lambda b, i, j: (b, i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, nzp, nyp, nx, R), jnp.int8),
-            jax.ShapeDtypeStruct((B, nzp, nyp, nx, R), jnp.int8),
-            jax.ShapeDtypeStruct((B, nzp, nyp, nx), jnp.int8),
-            jax.ShapeDtypeStruct((B, nzp, nyp, nx), jnp.int32),
-        ],
+        functools.partial(_fused_kernel, ny=ny, nx=nx),
+        grid=(B, nz, nyt),
+        in_specs=[tile(dz, dy) for dz in range(3) for dy in (-1, 0, 1)]
+        + [pl.BlockSpec((3, R, SUBLANES, LANES),
+                        lambda b, z, j: (_I32(0),) * 4)],
+        out_specs=[out, out],
+        out_shape=[shape, shape],
+        scratch_shapes=[pltpu.VMEM((27, SUBLANES, nxp), jnp.int32)],
         interpret=interpret,
-    )(vol, jnp.asarray(REF.OTH), jnp.asarray(REF.FID))
+    )(*[vol] * 9, fid_planes((SUBLANES, LANES)))
+
+
+def _device_rows(words, ny: int, nx: int):
+    """Packed rows from :func:`_fused_call` words, unpacked on the device
+    (for callers that keep the rows in a device program).  The bytes are
+    split off in the kernel's (…, y, x) layout and only int8 rows move
+    vertex-major: a (…, 19) int32 minor axis would pad to 128 lanes on a
+    TPU."""
+    st, pt = (jnp.moveaxis(_split_bytes(w[..., :ny, :nx], 2), 2, -1)
+              .reshape(-1, R + 1) for w in words)
+    return _split_rows(st, pt)
+
+
+def host_rows(words, ny: int, nx: int):
+    """Packed rows from :func:`_fused_call` words, unpacked on the host.
+
+    The device keeps the words in the kernel's (…, 19, y, x) layout (x on
+    lanes: no padding); the host crops, moves the word axis last and
+    reads each int32 word as its 4 little-endian row bytes."""
+    out = []
+    for w in words:
+        w = np.asarray(w)[..., :ny, :nx]
+        b = np.ascontiguousarray(np.moveaxis(w, 2, -1), dtype="<i4")
+        out.append(b.view(np.int8).reshape(-1, 4 * WORDS))
+    return _split_rows(*out, xp=np)
 
 
 def fused_cache_size() -> int:
@@ -285,63 +406,39 @@ def fused_cache_size() -> int:
     return _fused_call._cache_size()
 
 
-def _fused_finish(outs, nz, ny):
-    status, partner, vstat, vpart = outs
-    status = status[:, :nz, :ny].reshape(-1, R)
-    partner = partner[:, :nz, :ny].reshape(-1, R)
-    vstat = vstat[:, :nz, :ny].reshape(-1)
-    vpart = vpart[:, :nz, :ny].reshape(-1)
-    return status, partner, vstat, vpart
+def fused_words(grid, orders, *, rank_bound: int | None = None):
+    """Device half of :func:`fused_lower_star_gradient_pallas`: the
+    kernel's packed words for (nv,) or (B, nv) rank fields."""
+    nx, ny, nz = grid.dims
+    o = jnp.asarray(orders).reshape(-1, nz, ny, nx)
+    o = _as_int32(o, grid.nv if rank_bound is None else rank_bound)
+    vol = jnp.pad(o, ((0, 0), (1, 1), (0, 0), (0, 0)), constant_values=-1)
+    return _fused_call(vol, interpret=interpret_mode())
 
 
-def _tiles_for(nz: int, ny: int, tile_z: int, tile_y: int):
-    return max(1, min(tile_z, nz)), max(1, min(tile_y, ny))
-
-
-def fused_lower_star_gradient_pallas(grid, orders, *, tile_z: int = 4,
-                                     tile_y: int = 8, interpret: bool = True,
+def fused_lower_star_gradient_pallas(grid, orders, *,
                                      rank_bound: int | None = None):
     """Fused gather+pairing over a whole grid (optionally a batch of them).
 
     grid: :class:`repro.core.grid.Grid`; orders: (nv,) or (B, nv) rank
     fields in vid layout.  Returns packed rows over the flattened batch
     (status (B*nv, 74) int8, partner int8, vstat (B*nv,) int8, vpart
-    int32) — no (nv, 27) tensor ever touches HBM.
+    int32) as host arrays — no (nv, 27) tensor ever touches HBM.
     """
-    nx, ny, nz = grid.dims
-    orders = jnp.asarray(orders)
-    o = orders.reshape(-1, nz, ny, nx)
-    rank_bound = grid.nv if rank_bound is None else rank_bound
-    o = _maybe_int32(o, rank_bound)
-    tz, ty = _tiles_for(nz, ny, tile_z, tile_y)
-    nzp = -(-nz // tz) * tz
-    nyp = -(-ny // ty) * ty
-    vol = jnp.pad(o, ((0, 0), (1, nzp - nz + 1), (1, nyp - ny + 1), (1, 1)),
-                  constant_values=-1)
-    outs = _fused_call(vol, tz, ty, interpret, REF.use_packed_keys(rank_bound))
-    return _fused_finish(outs, nz, ny)
+    nx, ny, _ = grid.dims
+    return host_rows(fused_words(grid, orders, rank_bound=rank_bound), ny, nx)
 
 
-def fused_rows_from_halo_volume(ext, *, tile_z: int = 4, tile_y: int = 8,
-                                interpret: bool = True,
-                                rank_bound: int | None = None):
+def fused_rows_from_halo_volume(ext, *, rank_bound: int | None = None):
     """Fused kernel over a z-slab whose halo planes were exchanged already.
 
     ext: (nz_local+2, ny, nx) rank volume; the first/last z-planes are the
     ghost planes received from the ring neighbors (-1 at the global
-    boundary) — exactly the one-plane overlap the fused BlockSpecs need,
-    so the shardmap front-end feeds the kernel directly.  Returns packed
-    rows for the nz_local*ny*nx owned vertices.
+    boundary) — exactly the z halo the fused kernel reads, so the
+    shardmap front-end feeds the kernel directly.  Returns packed rows
+    for the nz_local*ny*nx owned vertices.
     """
-    nzh, ny, nx = ext.shape
-    nz = nzh - 2
-    ext = _maybe_int32(jnp.asarray(ext), rank_bound)
-    tz, ty = _tiles_for(nz, ny, tile_z, tile_y)
-    nzp = -(-nz // tz) * tz
-    nyp = -(-ny // ty) * ty
-    # z halos are already present; only the far z end, y and x get -1 pad
-    vol = jnp.pad(ext[None], ((0, 0), (0, nzp - nz), (1, nyp - ny + 1),
-                              (1, 1)), constant_values=-1)
-    outs = _fused_call(vol, tz, ty, interpret,
-                       REF.use_packed_keys(rank_bound))
-    return _fused_finish(outs, nz, ny)
+    _, ny, nx = ext.shape
+    ext = _as_int32(jnp.asarray(ext), rank_bound)
+    return _device_rows(_fused_call(ext[None], interpret=interpret_mode()),
+                        ny, nx)

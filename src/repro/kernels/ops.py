@@ -5,9 +5,9 @@
   gather and the pairing loop compile as ONE jit program (XLA fuses the
   gather, so no (nv, 27) tensor round-trips through HBM), with int32
   ranks and packed int64 keys whenever the grid allows.
-- ``"pallas"``         — the fused halo-aware Pallas kernel: the gather
-  happens inside the kernel from halo-overlapping volume tiles
-  (interpret mode on CPU, TPU target).
+- ``"pallas"``         — the fused Pallas kernel: the gather happens
+  inside the kernel from the z-planes and y-tiles around each tile
+  (compiled with Mosaic on a TPU, interpreted elsewhere).
 - ``"pallas_prepass"`` — the original im2col pre-pass + vertex-tiled
   Pallas kernel, kept as a fallback and oracle cross-check.
 """
@@ -23,7 +23,6 @@ from repro.core import gradient as GR
 from repro.core.grid import Grid
 from . import ref as REF
 from .lower_star import (fused_lower_star_gradient_pallas,
-                         fused_rows_from_halo_volume,
                          lower_star_gradient_pallas)
 
 BACKENDS = ("jax", "pallas", "pallas_prepass")
@@ -46,8 +45,7 @@ def jax_rows_cache_size() -> int:
     return _jax_full_rows._cache_size()
 
 
-def gradient_hbm_model(dims, tile_z: int = 4, tile_y: int = 8,
-                       rank_bytes=None):
+def gradient_hbm_model(dims, rank_bytes=None):
     """Modeled HBM gather traffic in bytes/vertex for each front-end path.
 
     Rank width follows the code: int32 when ``nv < 2**31`` (always for
@@ -56,20 +54,16 @@ def gradient_hbm_model(dims, tile_z: int = 4, tile_y: int = 8,
     - ``prepass``: the im2col pre-pass materializes a (nv, 27) rank
       tensor (27*w B/vertex written), the kernel reads it back, and the
       order field is read once: 27*w + 27*w + w (= 220 B/vertex at w=4).
-    - ``fused``: each halo-overlapping block reads its order window
-      exactly once; the one-vertex halo inflates the read by
-      (1 + 2/tile_z)(1 + 2/tile_y)(1 + 2/nx) — ~6-12 B/vertex.  The
-      ``jax`` backend is modeled the same way: XLA fuses the 27-slice
-      gather into the pairing program, so no im2col tensor round-trips.
+    - ``fused``: each (8, x) tile reads the 3 z-planes x 3 y-tiles
+      around it, x padded to the 128-lane tile and y to 8 rows:
+      9*w*pad B/vertex (36 B/vertex at w=4 when x is a multiple of 128).
     """
     nx, ny, nz = dims
     if rank_bytes is None:
         rank_bytes = 4.0 if nx * ny * nz < 2 ** 31 else 8.0
     w = float(rank_bytes)
-    tz = max(1, min(tile_z, nz))
-    ty = max(1, min(tile_y, ny))
-    overlap = (1 + 2 / tz) * (1 + 2 / ty) * (1 + 2 / nx)
-    return {"prepass": 27 * w + 27 * w + w, "fused": w * overlap}
+    pad = (-(-nx // 128) * 128 / nx) * (-(-ny // 8) * 8 / ny)
+    return {"prepass": 27 * w + 27 * w + w, "fused": 9 * w * pad}
 
 
 @jax.jit
@@ -87,33 +81,19 @@ def _halo_rows_jax(ext):
     return REF.lower_star_gradient_jnp(nbrs, ov, rank_bound=None)
 
 
-def lower_star_rows_halo(ext, backend: str = "jax"):
+def lower_star_rows_halo(ext):
     """Packed gradient rows for one halo-extended z-slab (streaming entry).
 
     The out-of-core scheduler (``repro.stream``) calls this once per
     chunk: ``ext`` is the chunk's (nzl+2, ny, nx) packed-key volume with
-    exchanged/loaded ghost planes (-1 outside the grid), exactly the
-    layout the fused kernel's overlapping BlockSpecs want.  Keys are
-    *rank-free* — full-width int64, so the int32/packed-key narrowings
-    stay off (``rank_bound=None``) on every path."""
-    ext = jnp.asarray(ext)
-    if backend == "jax":
-        return _halo_rows_jax(ext)
-    if backend == "pallas":
-        return fused_rows_from_halo_volume(ext, rank_bound=None)
-    if backend == "pallas_prepass":
-        nzh, ny, nx = ext.shape
-        eg = Grid.of(nx, ny, nzh)
-        nbrs = GR.neighbor_orders(eg, ext.reshape(-1), xp=jnp)
-        nbrs = nbrs.reshape(nzh, ny * nx, 27)[1:-1].reshape(-1, 27)
-        return lower_star_gradient_pallas(nbrs, ext[1:-1].reshape(-1),
-                                          interpret=True, rank_bound=None)
-    raise ValueError(f"unknown streaming backend {backend!r}; expected "
-                     f"{BACKENDS}")
+    exchanged/loaded ghost planes (-1 outside the grid).  Keys are
+    *rank-free* — full-width int64, which the int32 Pallas kernels do not
+    take — so the XLA ``jax`` kernel is the one streaming kernel."""
+    return _halo_rows_jax(jnp.asarray(ext))
 
 
 def lower_star_gradient(grid: Grid, order, backend: str = "jax",
-                        tile: int = 256):
+                        tile: int = 1024):
     """Compute per-vertex packed gradient rows for the whole grid."""
     order = jnp.asarray(order)
     if backend == "jax":
@@ -123,6 +103,5 @@ def lower_star_gradient(grid: Grid, order, backend: str = "jax",
     if backend == "pallas_prepass":
         nbrs = neighbor_orders_jnp(grid, order)
         return lower_star_gradient_pallas(nbrs, order, tile=tile,
-                                          interpret=True,
                                           rank_bound=grid.nv)
     raise ValueError(f"unknown backend {backend!r}; expected {BACKENDS}")

@@ -256,10 +256,7 @@ def pair_extrema_saddles_kernel(g: ExtremumGraph) -> ExtremaPairs:
     c1p = np.full(n_pad, m_pad - 1, dtype=np.int64)
     c0p[:n], c1p[:n] = c0, c1
 
-    try:
-        round_fn = _d0_round(n_pad, m_pad)
-    except Exception:                          # pragma: no cover - no jax
-        round_fn = None
+    round_fn = _d0_round(n_pad, m_pad)
     rep = np.arange(m_pad, dtype=np.int64)
     repkey = np.full(m_pad, NOKEY, dtype=np.int64)
     pair = np.full(m_pad, -1, dtype=np.int64)
@@ -268,13 +265,9 @@ def pair_extrema_saddles_kernel(g: ExtremumGraph) -> ExtremaPairs:
     while True:
         n_rounds += 1
         with maybe_span(tr, "d0_round", round=n_rounds):
-            if round_fn is not None:
-                new_rep, new_repkey, new_pair = (
-                    np.asarray(a) for a in round_fn(c0p, c1p, skey, ekey,
-                                                    rep, repkey))
-            else:                              # pragma: no cover - no jax
-                new_rep, new_repkey, new_pair = _d0_round_np(
-                    c0p, c1p, skey, ekey, rep, repkey, m_pad)
+            new_rep, new_repkey, new_pair = (
+                np.asarray(a) for a in round_fn(c0p, c1p, skey, ekey,
+                                                rep, repkey))
         if (np.array_equal(new_rep, rep) and np.array_equal(new_pair, pair)
                 and np.array_equal(new_repkey, repkey)):
             break
@@ -288,32 +281,6 @@ def pair_extrema_saddles_kernel(g: ExtremumGraph) -> ExtremaPairs:
     mask[e_idx] = False
     unpaired = [int(x) for x in nodes[mask]]   # nodes are unique-sorted
     return ExtremaPairs(pairs, unpaired)
-
-
-def _d0_round_np(c0, c1, skey, ekey, rep, repkey,
-                 m_pad):                       # pragma: no cover - no jax
-    """Numpy fallback of the jitted round (identical semantics)."""
-    cur = np.stack([c0, c1], axis=1)
-    while True:
-        step = repkey[cur] < skey[:, None]
-        if not step.any():
-            break
-        cur = np.where(step, rep[cur], cur)
-    r0, r1 = cur[:, 0], cur[:, 1]
-    prop = r0 != r1
-    younger = ekey[r0] >= ekey[r1]
-    die = np.where(younger, r0, r1)
-    live = np.where(younger, r1, r0)
-    win = np.full(m_pad, NOKEY, dtype=np.int64)
-    np.minimum.at(win, die[prop], skey[prop])
-    is_win = prop & (win[die] == skey)
-    new_rep = np.arange(m_pad, dtype=np.int64)
-    new_repkey = np.full(m_pad, NOKEY, dtype=np.int64)
-    new_pair = np.full(m_pad, -1, dtype=np.int64)
-    new_rep[die[is_win]] = live[is_win]
-    new_repkey[die[is_win]] = skey[is_win]
-    new_pair[die[is_win]] = np.nonzero(is_win)[0]
-    return new_rep, new_repkey, new_pair
 
 
 # --------------------------------------------------------------------------

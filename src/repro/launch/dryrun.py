@@ -270,7 +270,6 @@ DDMS_FIELDS = {
 def lower_ddms(field: str, multi_pod: bool, crit_cap: int = 4096,
                ring_rotations: int = 2, gradient_chunk=262144,
                use_sample_sort: bool = True):
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.shardmap_pipeline import (FrontConfig,
                                                      _front_out_specs,
                                                      front_device_fn)
@@ -287,8 +286,9 @@ def lower_ddms(field: str, multi_pod: bool, crit_cap: int = 4096,
     out_specs = {k: (P() if v == P() else spec_in)
                  for k, v in _front_out_specs().items()}
 
-    fn = shard_map(lambda f: front_device_fn(cfg, f), mesh=mesh,
-                   in_specs=spec_in, out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(lambda f: front_device_fn(cfg, f), mesh=mesh,
+                       in_specs=spec_in, out_specs=out_specs,
+                       check_vma=False)
     nv = int(np.prod(dims))
     f_abs = jax.ShapeDtypeStruct((nv,), jnp.float32,
                                  sharding=NamedSharding(mesh, spec_in))

@@ -38,8 +38,8 @@ from repro.core.diagram import Diagram
 from repro.core.grid import Grid, vertex_order
 from repro.obs.trace import Trace, current_trace, maybe_span, trace_active
 
-from .backends import (Backend, SandwichBackend, get_backend,
-                       get_sandwich_backend)
+from .backends import (Backend, SandwichBackend, default_backend_name,
+                       get_backend, get_sandwich_backend)
 from .plan import Executable, Plan, PlanCache, default_plan_cache
 from .request import TopoRequest, strip_field
 from .result import DiagramResult, PipelineResult  # noqa: F401  (re-export)
@@ -94,7 +94,8 @@ class PersistencePipeline:
     ----------
     backend : registry name ("np", "jax", "pallas", "shardmap") or a
         :class:`Backend` instance — the default for requests that do
-        not name one.
+        not name one.  None picks it from the platform: the compiled
+        Pallas kernel on a TPU, the ``np`` reference elsewhere.
     n_blocks : z-slab block count for the distributed engines.
     distributed : use the round-synchronous self-correcting pairing and
         the token-based D1 (the DDMS back-end).  Defaults to
@@ -108,11 +109,14 @@ class PersistencePipeline:
         process-wide shared :func:`default_plan_cache`.
     """
 
-    def __init__(self, backend: str = "np", *, n_blocks: int = 1,
+    def __init__(self, backend: Union[str, Backend, None] = None, *,
+                 n_blocks: int = 1,
                  distributed: Optional[bool] = None,
                  anticipation: bool = True, budget: Optional[int] = None,
                  sandwich_backend: Optional[str] = None,
                  plan_cache: Optional[PlanCache] = None):
+        if backend is None:
+            backend = default_backend_name()
         be = backend if isinstance(backend, Backend) else get_backend(backend)
         sb = sandwich_backend if sandwich_backend is not None else "jax"
         self.config = PipelineConfig(
@@ -182,11 +186,12 @@ class PersistencePipeline:
         be = self._get_backend(backend)
         streamed = req.is_stream
         if streamed and not be.caps.streamed:
-            if be.caps.sharded:
-                # composed sharded-streaming engine: the shard_map device
-                # program is replaced by host-thread shard workers that
-                # stream their z-slabs through the per-chunk streaming
-                # kernels ("jax"), exchanging boundary key planes
+            if be.caps.jittable:
+                # the chunks carry rank-free int64 keys, which only the XLA
+                # "jax" chunk kernel takes; a sharded backend becomes the
+                # composed sharded-streaming engine (host-thread shard
+                # workers streaming their z-slabs through that kernel,
+                # exchanging boundary key planes)
                 backend, be = "jax", self._get_backend("jax")
             else:
                 from .backends import available_backends
@@ -413,12 +418,10 @@ class PersistencePipeline:
         with report.stage("gradient") as rep:
             if plan.n_blocks > 1:
                 out = sharded_stream_front(
-                    src, plan.n_blocks, kernel=plan.backend,
-                    chunk_z=chunk_z, chunk_budget=chunk_budget,
-                    stage_report=rep)
+                    src, plan.n_blocks, chunk_z=chunk_z,
+                    chunk_budget=chunk_budget, stage_report=rep)
             else:
-                out = stream_front(src, kernel=plan.backend,
-                                   chunk_z=chunk_z,
+                out = stream_front(src, chunk_z=chunk_z,
                                    chunk_budget=chunk_budget,
                                    stage_report=rep)
             rep.count(n_critical=sum(out.gf.n_critical().values()))

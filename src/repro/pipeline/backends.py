@@ -21,9 +21,9 @@ Registered backends:
 - ``jax``            — branchless masked-recomputation form; the stencil
   gather and pairing compile as one jit program (packed int64 keys,
   int32 ranks);
-- ``pallas``         — the *fused* halo-aware Pallas lower-star kernel:
-  the 27-point gather runs inside the kernel over halo-overlapping
-  volume tiles (interpret mode on CPU, TPU target);
+- ``pallas``         — the *fused* Pallas lower-star kernel: the
+  27-point gather runs inside the kernel over the z-planes and y-tiles
+  around each tile (Mosaic-compiled on a TPU, interpreted elsewhere);
 - ``pallas_prepass`` — the original im2col pre-pass + vertex-tiled
   Pallas kernel, kept as a fallback and cross-check;
 - ``shardmap``       — the device-level z-slab front-end: ``shard_map``
@@ -37,6 +37,9 @@ one compiled program instead of re-tracing per distinct B.
 
 ``register_backend`` is the extension point later scaling PRs (async
 collectives, multi-host, remote caches) plug into.
+
+The platform picks the default: :func:`default_backend_name` is the
+compiled ``pallas`` kernel on a TPU and the ``np`` reference elsewhere.
 """
 
 from __future__ import annotations
@@ -104,6 +107,14 @@ def get_backend(name: str) -> Backend:
 
 def available_backends() -> Dict[str, Backend]:
     return dict(_REGISTRY)
+
+
+def default_backend_name() -> str:
+    """The gradient backend a pipeline runs when none is named: the
+    Pallas kernel where it compiles (a TPU), the literal reference
+    elsewhere."""
+    from repro.kernels.lower_star import interpret_mode
+    return "np" if interpret_mode() else "pallas"
 
 
 # --------------------------------------------------------------------------
@@ -210,22 +221,23 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
 
     The stencil gather and the per-vertex pairing are both vertex-local,
     so a batch of B same-shape fields is just a (B*nv)-vertex problem —
-    one compiled program, one dispatch.  The whole rows program is jitted
-    for every kernel (pallas_call composes with jit in interpret mode),
-    and the batch dimension is bucket-padded with inert all(-1) fields so
-    nearby batch sizes share one compiled program.
+    one compiled program, one dispatch.  The whole device program is
+    jitted for every kernel (the fused kernel's words are unpacked on the
+    host after it), and the batch dimension is bucket-padded with inert
+    all(-1) fields so nearby batch sizes share one compiled program.
     """
     import jax
     import jax.numpy as jnp
     from repro.kernels import ref as REF
-    from repro.kernels.lower_star import (fused_lower_star_gradient_pallas,
+    from repro.kernels.lower_star import (fused_words, host_rows,
                                           lower_star_gradient_pallas)
 
     def fn(orders):  # (Bp, nv) rank fields
         if kernel == "pallas":
             # fused path: gather happens inside the kernel, the batch is a
-            # leading grid dimension — no (B*nv, 27) tensor materializes
-            return fused_lower_star_gradient_pallas(grid, orders)
+            # leading grid dimension — no (B*nv, 27) tensor materializes;
+            # the packed words are unpacked on the host
+            return fused_words(grid, orders)
         o = orders.astype(jnp.int32) if grid.nv < 2 ** 31 else orders
         nbrs = jax.vmap(
             lambda oo: GR.neighbor_orders(grid, oo, xp=jnp))(o)
@@ -233,7 +245,6 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
         flat_ov = o.reshape(-1)
         if kernel == "pallas_prepass":
             return lower_star_gradient_pallas(flat_nbrs, flat_ov,
-                                              interpret=True,
                                               rank_bound=grid.nv)
         return REF.lower_star_gradient_jnp(flat_nbrs, flat_ov,
                                            rank_bound=grid.nv)
@@ -250,6 +261,8 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
             pad = jnp.full((Bp - B, orders.shape[1]), -1, orders.dtype)
             orders = jnp.concatenate([orders, pad])
         rows = jfn(orders)
+        if kernel == "pallas":
+            rows = host_rows(rows, grid.dims[1], grid.dims[0])
         n = B * grid.nv
         return tuple(r[:n] for r in rows)
 
@@ -277,26 +290,29 @@ def _make_kernel_gradient(kernel: str) -> Callable:
 # shardmap — device-level z-slab front-end (mesh ring + halo exchange)
 # --------------------------------------------------------------------------
 
-def _gradient_shardmap(grid: Grid, order, *, n_blocks: int = 1,
-                       kernel: str = "jax") -> GradientField:
+def _gradient_shardmap(grid: Grid, order, *,
+                       n_blocks: int = 1) -> GradientField:
     """Lower-star gradient under ``shard_map``: each device owns a z-slab,
     exchanges its boundary rank planes with ring neighbors (``ppermute``),
     and runs the kernel on its own vertices — the gradient step of
-    ``repro.distributed.shardmap_pipeline.front_device_fn``."""
+    ``repro.distributed.shardmap_pipeline.front_device_fn``.  The per-slab
+    kernel is the compiled fused Pallas kernel on a TPU and the XLA
+    ``jax`` kernel elsewhere."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.shardmap_pipeline import (FrontConfig,
                                                      halo_gradient)
+    from repro.kernels.lower_star import interpret_mode
 
     n_dev = len(jax.devices())
     if n_blocks > n_dev:
         raise ValueError(
             f"shardmap backend needs {n_blocks} devices, have {n_dev} "
             "(set XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    cfg = FrontConfig(grid.dims, n_blocks, gradient_backend=kernel)
+    cfg = FrontConfig(grid.dims, n_blocks,
+                      gradient_backend="jax" if interpret_mode() else "fused")
     cfg.nz_local  # eager divisibility check
     mesh = jax.make_mesh((n_blocks,), ("blocks",))
 
@@ -304,8 +320,8 @@ def _gradient_shardmap(grid: Grid, order, *, n_blocks: int = 1,
         _, rows = halo_gradient(cfg, o_slab)
         return rows
 
-    fn = shard_map(dev_fn, mesh=mesh, in_specs=P("blocks"),
-                   out_specs=P("blocks"), check_rep=False)
+    fn = jax.shard_map(dev_fn, mesh=mesh, in_specs=P("blocks"),
+                       out_specs=P("blocks"), check_vma=False)
     o = jnp.asarray(np.asarray(order).reshape(-1), jnp.int64)
     status, partner, vstat, vpart = jax.jit(fn)(o)
     return GR._scatter_results(grid, np.asarray(status), np.asarray(partner),
@@ -329,15 +345,13 @@ register_backend(Backend(
 
 register_backend(Backend(
     name="pallas", gradient=_make_kernel_gradient("pallas"),
-    caps=BackendCaps(jittable=True, batched=True, fused=True,
-                     streamed=True),
-    description="fused halo-aware Pallas lower-star kernel "
-                "(interpret mode on CPU)",
+    caps=BackendCaps(jittable=True, batched=True, fused=True),
+    description="fused Pallas lower-star kernel (Mosaic on a TPU)",
     batched_rows=lambda grid: _rows_fn(grid, "pallas")))
 
 register_backend(Backend(
     name="pallas_prepass", gradient=_make_kernel_gradient("pallas_prepass"),
-    caps=BackendCaps(jittable=True, batched=True, streamed=True),
+    caps=BackendCaps(jittable=True, batched=True),
     description="im2col pre-pass + vertex-tiled Pallas kernel (fallback)",
     batched_rows=lambda grid: _rows_fn(grid, "pallas_prepass")))
 

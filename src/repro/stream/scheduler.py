@@ -11,10 +11,10 @@ resident.  Per chunk:
 2. the slab is packed into rank-free ``(value, vid)`` int64 keys
    (:func:`~repro.stream.chunks.pack_value_keys`) — no global argsort,
    no dense rank array, zero cross-chunk communication;
-3. the halo-extended key volume goes straight into the PR-2 kernels
-   (``repro.kernels.ops.lower_star_rows_halo`` → fused Pallas or the
-   one-jit jnp program) which return packed gradient rows for the owned
-   vertices;
+3. the halo-extended key volume goes straight into the XLA chunk
+   kernel (``repro.kernels.ops.lower_star_rows_halo``, the one-jit jnp
+   program — the only kernel that takes the int64 keys), which returns
+   packed gradient rows for the owned vertices;
 4. the rows scatter into global gradient arrays through the cached
    row→sid offset tables (``GR.scatter_rows_chunk``) and the owned keys
    land in the dense key array handed to the back-end.
@@ -160,15 +160,14 @@ def _ext_volume(keys_slab: np.ndarray, c: Chunk, dims,
     return np.concatenate([lo, k3[c.zlo - c.glo: c.zhi - c.glo], hi], axis=0)
 
 
-def stream_front(source: FieldSource, *, kernel: str = "jax",
+def stream_front(source: FieldSource, *,
                  chunk_z: Optional[int] = None,
                  chunk_budget: Optional[int] = None,
                  stage_report=None) -> StreamResult:
     """Run the lower-star gradient over ``source`` chunk by chunk.
 
-    kernel: a streaming-capable kernel name ("jax", "pallas",
-    "pallas_prepass" — see ``lower_star_rows_halo``).  Exactly one of
-    ``chunk_z`` (owned planes per chunk) / ``chunk_budget`` (bytes of
+    Chunks run the XLA chunk kernel (``lower_star_rows_halo``).  Exactly
+    one of ``chunk_z`` (owned planes per chunk) / ``chunk_budget`` (bytes of
     loaded field per chunk) selects the decomposition.  ``stage_report``,
     if given, is a ``StageReport`` that receives load/compute/scatter
     child timings and the headline counters."""
@@ -184,7 +183,7 @@ def stream_front(source: FieldSource, *, kernel: str = "jax",
     offsets = GR.row_sid_offsets(grid)
     keys = np.empty(grid.nv, dtype=np.int64)
     rep = StreamReport(
-        dims=grid.dims, backend=kernel, n_chunks=len(chunks),
+        dims=grid.dims, backend="jax", n_chunks=len(chunks),
         chunk_z=chunks[0].nz,
         max_chunk_bytes=max(c.load_bytes(grid.dims) for c in chunks),
         key_bytes=keys.nbytes)
@@ -227,7 +226,7 @@ def stream_front(source: FieldSource, *, kernel: str = "jax",
                 kslab = pack_value_keys(slab, vids)
                 ext = _ext_volume(kslab, c, grid.dims)
                 rows = [np.asarray(r) for r in
-                        ops.lower_star_rows_halo(ext, backend=kernel)]
+                        ops.lower_star_rows_halo(ext)]
             rep.compute_s += time.perf_counter() - t0
 
             t0 = time.perf_counter()

@@ -117,14 +117,11 @@ class HaloExchange:
 
 
 def _shard_device(s: int):
-    """Context pinning shard ``s``'s kernels to host device ``s % N``."""
-    try:
-        import jax
-        devs = jax.devices()
-        if len(devs) > 1:
-            return jax.default_device(devs[s % len(devs)])
-    except Exception:
-        pass
+    """Context pinning shard ``s``'s kernels to device ``s % N``."""
+    import jax
+    devs = jax.devices()
+    if len(devs) > 1:
+        return jax.default_device(devs[s % len(devs)])
     return nullcontext()
 
 
@@ -136,7 +133,6 @@ def _pack_plane(source: FieldSource, z: int, plane: int) -> np.ndarray:
 
 
 def sharded_stream_front(source: FieldSource, n_shards: int, *,
-                         kernel: str = "jax",
                          chunk_z: Optional[int] = None,
                          chunk_budget: Optional[int] = None,
                          stage_report=None) -> StreamResult:
@@ -273,7 +269,7 @@ def sharded_stream_front(source: FieldSource, n_shards: int, *,
                     ext = _ext_volume(kslab, c, grid.dims,
                                       halo_lo=halo_lo, halo_hi=halo_hi)
                     rows = [np.asarray(r) for r in
-                            ops.lower_star_rows_halo(ext, backend=kernel)]
+                            ops.lower_star_rows_halo(ext)]
                 st["compute_s"] += time.perf_counter() - t0
 
                 t0 = time.perf_counter()
@@ -305,7 +301,7 @@ def sharded_stream_front(source: FieldSource, n_shards: int, *,
     wall_s = time.perf_counter() - t_wall
 
     rep = StreamReport(
-        dims=grid.dims, backend=kernel,
+        dims=grid.dims, backend="jax",
         n_chunks=sum(len(cs) for cs in shard_chunks),
         chunk_z=shard_chunks[0][0].nz,
         max_chunk_bytes=max(c.load_bytes(grid.dims)

@@ -27,8 +27,9 @@ def test_pallas_matches_ref(dims, dtype):
     order = vertex_order(f).astype(dtype)
     nbrs = ops.neighbor_orders_jnp(g, jnp.asarray(order))
     ref = lower_star_gradient_jnp(nbrs, jnp.asarray(order))
+    # int64 ranks reach the int32 kernel through the rank bound
     got = lower_star_gradient_pallas(nbrs, jnp.asarray(order), tile=128,
-                                     interpret=True)
+                                     rank_bound=g.nv)
     for a, b, name in zip(ref, got, ["status", "partner", "vstat", "vpart"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
 
@@ -42,9 +43,37 @@ def test_pallas_tile_sweep(tile):
     nbrs = ops.neighbor_orders_jnp(g, jnp.asarray(order))
     ref = lower_star_gradient_jnp(nbrs, jnp.asarray(order))
     got = lower_star_gradient_pallas(nbrs, jnp.asarray(order), tile=tile,
-                                     interpret=True)
+                                     rank_bound=g.nv)
     for a, b in zip(ref, got):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kernel", ["prepass", "fused"])
+def test_pallas_refuses_unbounded_int64(kernel):
+    """64-bit keys with no rank bound never reach a Pallas kernel."""
+    from repro.kernels.lower_star import fused_rows_from_halo_volume
+    g = Grid.of(4, 3, 2)
+    order = jnp.asarray(vertex_order(np.arange(g.nv, dtype=float)),
+                        jnp.int64)
+    with pytest.raises(TypeError, match="int32"):
+        if kernel == "prepass":
+            nbrs = ops.neighbor_orders_jnp(g, order)
+            lower_star_gradient_pallas(nbrs, order)
+        else:
+            fused_rows_from_halo_volume(order.reshape(2, 3, 4))
+
+
+def test_interpret_mode_follows_platform():
+    """Interpret mode is the platform's call: on the CPU the kernels are
+    interpreted and the pipeline default stays the np reference."""
+    import jax
+    from repro.kernels.lower_star import interpret_mode
+    from repro.pipeline import PersistencePipeline
+    from repro.pipeline.backends import default_backend_name
+    assert jax.default_backend() == "cpu"
+    assert interpret_mode() is True
+    assert default_backend_name() == "np"
+    assert PersistencePipeline().backend.name == "np"
 
 
 def test_pallas_backend_end_to_end():

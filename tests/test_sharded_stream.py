@@ -122,7 +122,7 @@ class TestShardedStreamFront:
         gf_ref = compute_gradient(g, np.asarray(vertex_order(f)),
                                   backend="jax")
         out = sharded_stream_front(ArraySource(vol(f, dims)), 4,
-                                   kernel="jax", chunk_z=3)
+                                   chunk_z=3)
         for k in gf_ref.crit:
             assert np.array_equal(out.gf.crit[k], gf_ref.crit[k]), k
         for k in gf_ref.pair_up:
@@ -137,7 +137,7 @@ class TestShardedStreamFront:
         dims = (8, 8, 40)
         f = make_field("random", dims, seed=0)
         out = sharded_stream_front(ArraySource(vol(f, dims)), 4,
-                                   kernel="jax", chunk_z=3)
+                                   chunk_z=3)
         rep = out.report
         assert rep.n_shards == 4
         assert len(rep.per_shard) == 4
@@ -161,7 +161,7 @@ class TestShardedStreamFront:
         dims = (5, 4, 9)
         f = make_field("wavelet", dims, seed=0)
         out = sharded_stream_front(ArraySource(vol(f, dims)), 1,
-                                   kernel="jax", chunk_z=4)
+                                   chunk_z=4)
         assert out.report.n_shards == 1
         assert out.report.comm_s == 0.0
         assert out.report.overlap_fraction is None

@@ -154,7 +154,7 @@ class TestStreamFront:
     def test_peak_resident_bounded_by_two_chunks(self):
         dims = (8, 8, 32)
         f = make_field("random", dims, seed=0)
-        out = stream_front(ArraySource(vol(f, dims)), kernel="jax",
+        out = stream_front(ArraySource(vol(f, dims)),
                            chunk_z=4)
         rep = out.report
         assert rep.n_chunks == 8
@@ -174,7 +174,7 @@ class TestStreamFront:
         from repro.core.gradient import compute_gradient
         gf_ref = compute_gradient(g, np.asarray(vertex_order(f)),
                                   backend="jax")
-        out = stream_front(ArraySource(vol(f, dims)), kernel="jax",
+        out = stream_front(ArraySource(vol(f, dims)),
                            chunk_z=3)
         for k in gf_ref.crit:
             assert np.array_equal(out.gf.crit[k], gf_ref.crit[k]), k
