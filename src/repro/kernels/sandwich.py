@@ -50,7 +50,6 @@ gradient must fail loudly, not silently mis-pair.
 from __future__ import annotations
 
 import heapq
-import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -125,27 +124,32 @@ def extract_critical_kernel(grid: Grid, gf: GradientField,
     # them to [0, nv) so the edge-key packing below always fits
     o = order if order.size == 0 or int(order.max()) < 2 ** 31 \
         else _rank_compress(order)
-    crit_sids: Dict[int, np.ndarray] = {}
+    tr = current_trace()
+    dims = range(grid.dim + 1)
+    with maybe_span(tr, "extract_sort.critical"):
+        crit = {k: gf.critical_sids(k) for k in dims}
     ranks: Dict[int, np.ndarray] = {}
-    for k in range(grid.dim + 1):
-        cs = gf.critical_sids(k)
-        if k == 0:
-            # the vertex rank IS the vertex order (rank-compressed)
-            ranks[0] = o.astype(np.int64)
-        elif k == 1:
+    if grid.dim >= 1:
+        with maybe_span(tr, "extract_sort.edge_keys"):
             ranks[1] = edge_keys_kernel(grid, o)
-        else:
-            # rank among critical simplices only — the only comparisons
-            # that ever happen in dimensions >= 2
-            keys = np.asarray(grid.simplex_key(k, cs, o)) if len(cs) \
-                else np.zeros((0, k + 1), np.int64)
-            perm = np.lexsort(tuple(keys[:, c]
-                                    for c in range(k, -1, -1)))
-            rk = np.full(grid.sid_space(k), -1, dtype=np.int64)
-            rk[cs[perm]] = np.arange(len(cs), dtype=np.int64)
-            ranks[k] = rk
-        crit_sids[k] = cs[np.argsort(ranks[k][cs], kind="stable")]
-    return CriticalInfo(grid, order, crit_sids, ranks)
+    crit_sids: Dict[int, np.ndarray] = {}
+    with maybe_span(tr, "extract_sort.rank"):
+        # the vertex rank IS the vertex order (rank-compressed)
+        ranks[0] = o.astype(np.int64)
+        for k in dims:
+            cs = crit[k]
+            if k >= 2:
+                # rank among critical simplices only — the only
+                # comparisons that ever happen in dimensions >= 2
+                keys = np.asarray(grid.simplex_key(k, cs, o)) if len(cs) \
+                    else np.zeros((0, k + 1), np.int64)
+                perm = np.lexsort(tuple(keys[:, c]
+                                        for c in range(k, -1, -1)))
+                rk = np.full(grid.sid_space(k), -1, dtype=np.int64)
+                rk[cs[perm]] = np.arange(len(cs), dtype=np.int64)
+                ranks[k] = rk
+            crit_sids[k] = cs[np.argsort(ranks[k][cs], kind="stable")]
+    return CriticalInfo(grid, order, crit_sids, {k: ranks[k] for k in dims})
 
 
 # --------------------------------------------------------------------------
@@ -430,6 +434,7 @@ def _pair_d1_burst(grid: Grid, pair_up1: np.ndarray, is_c1: np.ndarray,
     pair_edge = np.full(n2, -1, dtype=np.int64)
     expansions = 0
     rounds = 0
+    tr = current_trace()
     for g in range(n2):
         h = [(-int(erank[e]), e) for e in faces3(int(order_c2[g]))]
         heapq.heapify(h)
@@ -445,31 +450,33 @@ def _pair_d1_burst(grid: Grid, pair_up1: np.ndarray, is_c1: np.ndarray,
             if piv is None:
                 break                    # boundary vanished: essential
             rounds += 1
-            e = piv[1]
-            up = int(pair_up1[e])
-            if up >= 0:
+            # a pivot step is this path's round: one span each
+            with maybe_span(tr, "d1_round", round=rounds):
+                e = piv[1]
+                up = int(pair_up1[e])
+                if up >= 0:
+                    expansions += 1
+                    for f in faces3(up):  # XOR ∂V(e); popped e cancels
+                        if f != e:
+                            heapq.heappush(h, (-int(erank[f]), f))
+                    continue
+                if not is_c1[e]:
+                    err = GradientInvariantError(
+                        f"D1 propagation reached edge sid {e}, which is "
+                        f"neither gradient-paired upward nor an unpaired "
+                        f"critical edge: a 1-cycle's highest edge must be "
+                        f"positive — the gradient field is inconsistent")
+                    _flight.crash_dump("gradient_invariant", exc=err)
+                    raise err
+                holder = claim.get(e)
+                if holder is None:
+                    claim[e] = g
+                    pair_edge[g] = e
+                    stored[g] = h        # pivot excluded: a merge cancels
+                    break                # it by never re-adding it
                 expansions += 1
-                for f in faces3(up):     # XOR ∂V(e); the popped e cancels
-                    if f != e:
-                        heapq.heappush(h, (-int(erank[f]), f))
-                continue
-            if not is_c1[e]:
-                err = GradientInvariantError(
-                    f"D1 propagation reached edge sid {e}, which is "
-                    f"neither gradient-paired upward nor an unpaired "
-                    f"critical edge: a 1-cycle's highest edge must be "
-                    f"positive — the gradient field is inconsistent")
-                _flight.crash_dump("gradient_invariant", exc=err)
-                raise err
-            holder = claim.get(e)
-            if holder is None:
-                claim[e] = g
-                pair_edge[g] = e
-                stored[g] = h            # pivot excluded: a merge cancels
-                break                    # it by never re-adding it
-            expansions += 1
-            for entry in stored[holder]:
-                heapq.heappush(h, entry)
+                for entry in stored[holder]:
+                    heapq.heappush(h, entry)
     return pair_edge, expansions, rounds
 
 
@@ -556,125 +563,119 @@ def pair_saddle_saddle_wavefront(grid: Grid, gf: GradientField,
             if len(idx) == 0:
                 break
             rounds += 1
-            # round spans bracket manually (Trace.complete): the body
-            # exits through several continue paths
-            _rt0 = time.perf_counter() if tr is not None else 0.0
-            piv = rows[idx, -1]                  # sorted rows: pivot last
-            mx = keys[idx, -1]
-            # -- retirement: column vanished -> essential 2-class -------
-            empty = mx == NEG_INF
-            if empty.any():
-                active[idx[empty]] = False
-                idx, piv = idx[~empty], piv[~empty]
-                if len(idx) == 0:
-                    if tr is not None:
-                        tr.complete("d1_round", _rt0, round=rounds)
-                    continue
-            # -- classify the live pivots ------------------------------
-            up = pair_up1[piv]
-            expand = up >= 0
-            crit = ~expand
-            ex_rows = idx[expand]
-            mg_rows = np.zeros(0, dtype=np.int64)
-            mg_bounds: List[np.ndarray] = []
-            if crit.any():
-                bad = ~is_c1[piv[crit]]
-                if bad.any():
-                    e = int(piv[crit][bad][0])
-                    err = GradientInvariantError(
-                        f"D1 propagation reached edge sid {e}, which is "
-                        f"neither gradient-paired upward nor an unpaired "
-                        f"critical edge: a 1-cycle's highest edge must be "
-                        f"positive — the gradient field is inconsistent")
-                    _flight.crash_dump("gradient_invariant", exc=err)
-                    raise err
-                # -- critical pivots: merge / contest ------------------
-                crit_rows = idx[crit]
-                cpiv = piv[crit]
-                holder = claim[cpiv]             # global index or -1
-                mine = crit_rows + lo            # global index of each
-                merge = (holder >= 0) & (holder < mine)
-                contest = ~merge                 # unclaimed, or stealable
-                # contest winner per pivot: the lowest-rank (= lowest
-                # global index) column wins; the others wait a round
-                if contest.any():
-                    cand_rows = crit_rows[contest]
-                    cand_piv = cpiv[contest]
-                    win[cand_piv] = NOKEY        # reset only touched slots
-                    np.minimum.at(win, cand_piv, cand_rows + lo)
-                    is_win = win[cand_piv] == cand_rows + lo
-                    wrows = cand_rows[is_win]
-                    wpiv = cand_piv[is_win]
-                    # steal: the displaced (younger) holder reopens; next
-                    # round it sees the new claim and merges the winner
-                    old = claim[wpiv]
-                    reopen = old[old >= 0]
-                    reopen = reopen[(reopen >= lo) & (reopen < hi)]
-                    if len(reopen):
-                        active[reopen - lo] = True
-                        pair_edge[reopen] = -1
-                    claim[wpiv] = wrows + lo
-                    pair_edge[wrows + lo] = wpiv
-                    active[wrows] = False        # provisionally retired
-                mg_rows = crit_rows[merge]
-                for gidx in claim[cpiv[merge]]:
-                    b = stored[gidx] if gidx < lo else rows[gidx - lo]
-                    mg_bounds.append(b[b >= 0])
-            # -- apply the XOR ops (expansions + merges) in one batch --
-            op_rows = np.concatenate([ex_rows, mg_rows]) \
-                if len(mg_rows) else ex_rows
-            if len(op_rows) == 0:
-                if tr is not None:
-                    tr.complete("d1_round", _rt0, round=rounds)
-                continue                         # contest losers wait
-            expansions += len(op_rows)
-            ne = len(ex_rows)
-            aw = max([3] + [len(b) for b in mg_bounds])
-            add = np.full((len(op_rows), aw), -1, dtype=np.int64)
-            if ne:
-                add[:ne, :3] = faces_of(up[expand])
-            for r, b in enumerate(mg_bounds):
-                add[ne + r, :len(b)] = b
-            if len(mg_bounds):
-                addk = np.where(add >= 0, erank[np.maximum(add, 0)],
-                                NEG_INF)
-            else:
-                addk = erank[add]                # pure expansions: no holes
-            a, k = _xor_sorted(rows[op_rows], keys[op_rows], add, addk)
-            # -- re-compact right-aligned into the (maybe grown) width --
-            m = a >= 0
-            cnt = m.cumsum(axis=1)
-            live = cnt[:, -1]
-            W = rows.shape[1]
-            lmax = int(live.max()) if len(live) else 0
-            if lmax > W:                         # grow geometrically so
-                Wn = max(lmax, 2 * W)            # the copies amortize
-                gr = np.full((C, Wn), -1, dtype=np.int64)
-                gr[:, Wn - W:] = rows
-                gk = np.full((C, Wn), NEG_INF, dtype=np.int64)
-                gk[:, Wn - W:] = keys
-                rows, keys, W = gr, gk, Wn
-            # counting scatter with a trash slot: live entries land right-
-            # aligned in columns 1..W, holes all land in the (discarded)
-            # column 0 — no nonzero() pass over the whole op block
-            dest = np.where(m, (W + 1 - live)[:, None] + cnt - 1, 0)
-            na = np.full((len(op_rows), W + 1), -1, dtype=np.int64)
-            nk = np.full((len(op_rows), W + 1), NEG_INF, dtype=np.int64)
-            ar = np.arange(len(op_rows))[:, None]
-            na[ar, dest] = a
-            nk[ar, dest] = k
-            rows[op_rows] = na[:, 1:]
-            keys[op_rows] = nk[:, 1:]
-            nlive[op_rows] = live
-            # -- shrink once the peak has passed: per-round sort cost
-            # tracks the *current* widest row, not the historical peak --
-            wide = int(nlive.max())
-            if W > 8 and 2 * wide <= W:
-                Wn = max(wide, 4)
-                rows = rows[:, W - Wn:].copy()
-                keys = keys[:, W - Wn:].copy()
-            if tr is not None:
-                tr.complete("d1_round", _rt0, round=rounds)
+            # one span per round, over the whole body: a `continue`
+            # leaves the round through the span's exit like a fall-through
+            with maybe_span(tr, "d1_round", round=rounds):
+                piv = rows[idx, -1]                  # sorted rows: pivot last
+                mx = keys[idx, -1]
+                # -- retirement: column vanished -> essential 2-class -------
+                empty = mx == NEG_INF
+                if empty.any():
+                    active[idx[empty]] = False
+                    idx, piv = idx[~empty], piv[~empty]
+                    if len(idx) == 0:
+                        continue
+                # -- classify the live pivots ------------------------------
+                up = pair_up1[piv]
+                expand = up >= 0
+                crit = ~expand
+                ex_rows = idx[expand]
+                mg_rows = np.zeros(0, dtype=np.int64)
+                mg_bounds: List[np.ndarray] = []
+                if crit.any():
+                    bad = ~is_c1[piv[crit]]
+                    if bad.any():
+                        e = int(piv[crit][bad][0])
+                        err = GradientInvariantError(
+                            f"D1 propagation reached edge sid {e}, which is "
+                            f"neither gradient-paired upward nor an unpaired "
+                            f"critical edge: a 1-cycle's highest edge must be "
+                            f"positive — the gradient field is inconsistent")
+                        _flight.crash_dump("gradient_invariant", exc=err)
+                        raise err
+                    # -- critical pivots: merge / contest ------------------
+                    crit_rows = idx[crit]
+                    cpiv = piv[crit]
+                    holder = claim[cpiv]             # global index or -1
+                    mine = crit_rows + lo            # global index of each
+                    merge = (holder >= 0) & (holder < mine)
+                    contest = ~merge                 # unclaimed, or stealable
+                    # contest winner per pivot: the lowest-rank (= lowest
+                    # global index) column wins; the others wait a round
+                    if contest.any():
+                        cand_rows = crit_rows[contest]
+                        cand_piv = cpiv[contest]
+                        win[cand_piv] = NOKEY        # reset only touched slots
+                        np.minimum.at(win, cand_piv, cand_rows + lo)
+                        is_win = win[cand_piv] == cand_rows + lo
+                        wrows = cand_rows[is_win]
+                        wpiv = cand_piv[is_win]
+                        # steal: the displaced (younger) holder reopens; next
+                        # round it sees the new claim and merges the winner
+                        old = claim[wpiv]
+                        reopen = old[old >= 0]
+                        reopen = reopen[(reopen >= lo) & (reopen < hi)]
+                        if len(reopen):
+                            active[reopen - lo] = True
+                            pair_edge[reopen] = -1
+                        claim[wpiv] = wrows + lo
+                        pair_edge[wrows + lo] = wpiv
+                        active[wrows] = False        # provisionally retired
+                    mg_rows = crit_rows[merge]
+                    for gidx in claim[cpiv[merge]]:
+                        b = stored[gidx] if gidx < lo else rows[gidx - lo]
+                        mg_bounds.append(b[b >= 0])
+                # -- apply the XOR ops (expansions + merges) in one batch --
+                op_rows = np.concatenate([ex_rows, mg_rows]) \
+                    if len(mg_rows) else ex_rows
+                if len(op_rows) == 0:
+                    continue                         # contest losers wait
+                expansions += len(op_rows)
+                ne = len(ex_rows)
+                aw = max([3] + [len(b) for b in mg_bounds])
+                add = np.full((len(op_rows), aw), -1, dtype=np.int64)
+                if ne:
+                    add[:ne, :3] = faces_of(up[expand])
+                for r, b in enumerate(mg_bounds):
+                    add[ne + r, :len(b)] = b
+                if len(mg_bounds):
+                    addk = np.where(add >= 0, erank[np.maximum(add, 0)],
+                                    NEG_INF)
+                else:
+                    addk = erank[add]        # pure expansions: no holes
+                a, k = _xor_sorted(rows[op_rows], keys[op_rows], add, addk)
+                # -- re-compact right-aligned into the (maybe grown) width --
+                m = a >= 0
+                cnt = m.cumsum(axis=1)
+                live = cnt[:, -1]
+                W = rows.shape[1]
+                lmax = int(live.max()) if len(live) else 0
+                if lmax > W:                         # grow geometrically so
+                    Wn = max(lmax, 2 * W)            # the copies amortize
+                    gr = np.full((C, Wn), -1, dtype=np.int64)
+                    gr[:, Wn - W:] = rows
+                    gk = np.full((C, Wn), NEG_INF, dtype=np.int64)
+                    gk[:, Wn - W:] = keys
+                    rows, keys, W = gr, gk, Wn
+                # counting scatter with a trash slot: live entries land right-
+                # aligned in columns 1..W, holes all land in the (discarded)
+                # column 0 — no nonzero() pass over the whole op block
+                dest = np.where(m, (W + 1 - live)[:, None] + cnt - 1, 0)
+                na = np.full((len(op_rows), W + 1), -1, dtype=np.int64)
+                nk = np.full((len(op_rows), W + 1), NEG_INF, dtype=np.int64)
+                ar = np.arange(len(op_rows))[:, None]
+                na[ar, dest] = a
+                nk[ar, dest] = k
+                rows[op_rows] = na[:, 1:]
+                keys[op_rows] = nk[:, 1:]
+                nlive[op_rows] = live
+                # -- shrink once the peak has passed: per-round sort cost
+                # tracks the *current* widest row, not the historical peak --
+                wide = int(nlive.max())
+                if W > 8 and 2 * wide <= W:
+                    Wn = max(wide, 4)
+                    rows = rows[:, W - Wn:].copy()
+                    keys = keys[:, W - Wn:].copy()
         # batch done: freeze the claim-holding boundaries (later batches
         # can merge them but — being younger — can never steal them)
         for r in range(C):

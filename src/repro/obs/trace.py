@@ -34,6 +34,13 @@ Design:
 - when no trace is active every hook is one thread-local read and a
   ``None`` check; the ``BENCH_obs.json`` benchmark gates this disabled
   overhead at < 3% of an end-to-end pipeline run.
+- :func:`maybe_span` is the one entry point of the instrumented code
+  (``StageReport.stage`` goes through it too).  Whether or not a trace
+  is active, it also opens a ``jax.profiler.TraceAnnotation`` named
+  ``stage.<name>`` over the interval, so every span lands on the
+  profiler's timeline beside the device's operations (a no-op costing
+  well under a microsecond while no profiler session runs).  The kill
+  switch silences it with the rest of the layer.
 
 Export: :meth:`Trace.to_perfetto` writes the standard JSON object
 format (``{"traceEvents": [...]}``) — load it at ``ui.perfetto.dev``
@@ -46,7 +53,7 @@ from __future__ import annotations
 import json
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional
 
 __all__ = ["Span", "Trace", "current_trace", "trace_active",
@@ -61,6 +68,11 @@ DEFAULT_SINK = object()
 
 _FLIGHT = None    # lazily imported repro.obs.flight (avoids the cycle)
 
+# every span's name on the jax.profiler timeline is PROFILER_PREFIX + name
+PROFILER_PREFIX = "stage."
+_ANNOTATION = None   # jax.profiler.TraceAnnotation, imported at first use
+_NO_ANNOTATION = nullcontext()
+
 
 def _flight_active():
     """The active flight recorder, or None (kill switch off).  Lazy
@@ -70,6 +82,19 @@ def _flight_active():
     if _FLIGHT is None:
         from . import flight as _FLIGHT  # noqa: F811 - module cache
     return _FLIGHT.active_recorder()
+
+
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """The profiler-timeline twin of a span: a ``TraceAnnotation`` named
+    ``stage.<name>`` carrying the span's attributes, or a no-op context
+    while the kill switch is off.  ``jax`` is imported at first use, so
+    importing ``repro.obs`` never pulls it in."""
+    global _ANNOTATION
+    if not _ENABLED:
+        return _NO_ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation as _ANNOTATION  # noqa: F811
+    return _ANNOTATION(PROFILER_PREFIX + name, **attrs)
 
 
 class Span:
@@ -162,20 +187,6 @@ class Trace:
             rec = self._sink()
             if rec is not None:
                 rec.record(name, t0, sp.dur, sp.args or None)
-
-    def complete(self, name: str, t0: float, **attrs) -> Span:
-        """Record an already-measured interval: started at
-        ``perf_counter`` time ``t0``, ending now.  For loops that
-        cannot wrap their round body in a ``with`` block (e.g. bodies
-        with ``continue`` paths)."""
-        buf = self._buf()
-        sp = Span(name, t0 - self.epoch, buf.tid, attrs)
-        sp.dur = time.perf_counter() - t0
-        buf.spans.append(sp)
-        rec = self._sink()
-        if rec is not None:
-            rec.record(name, t0, sp.dur, sp.args or None)
-        return sp
 
     def instant(self, name: str, **attrs) -> Span:
         """Record a zero-duration marker on the calling thread."""
@@ -278,13 +289,18 @@ def current_trace() -> Optional[Trace]:
 
 @contextmanager
 def maybe_span(trace: Optional[Trace], name: str, **attrs):
-    """``trace.span(...)`` when ``trace`` is a Trace; otherwise the
+    """The one span entry point of the instrumented code.
+
+    ``trace.span(...)`` when ``trace`` is a Trace; otherwise the
     interval is still timed into the process **flight recorder** (the
-    always-on last-N-events tail — see :mod:`repro.obs.flight`) unless
-    the kill switch is off, in which case this is a no-op yielding
-    None — the one-liner instrumented loops use on every path."""
+    always-on last-N-events tail — see :mod:`repro.obs.flight`) and
+    yields None.  On both paths the interval is also a
+    ``jax.profiler.TraceAnnotation`` named ``stage.<name>`` (see
+    :func:`_annotation`), so a profiler session sees every span on the
+    clock of the device trace.  With the kill switch off, an untraced
+    call records nothing at all."""
     if trace is not None:
-        with trace.span(name, **attrs) as sp:
+        with _annotation(name, attrs), trace.span(name, **attrs) as sp:
             yield sp
         return
     rec = _flight_active()
@@ -292,10 +308,11 @@ def maybe_span(trace: Optional[Trace], name: str, **attrs):
         yield None
         return
     t0 = time.perf_counter()
-    try:
-        yield None
-    finally:
-        rec.record(name, t0, time.perf_counter() - t0, attrs or None)
+    with _annotation(name, attrs):
+        try:
+            yield None
+        finally:
+            rec.record(name, t0, time.perf_counter() - t0, attrs or None)
 
 
 @contextmanager

@@ -374,17 +374,21 @@ class PersistencePipeline:
                 state.order = np.asarray(vertex_order(state.f))
 
         # one batched gradient dispatch for the whole batch
+        tr = current_trace()
         t0 = time.perf_counter()
-        with maybe_span(current_trace(), "gradient", batch_size=B):
+        with maybe_span(tr, "gradient", batch_size=B):
             orders = np.stack([s.order for s in states])
             rows = ex.rows_program(orders)
-            gfs = _scatter_batch(grid, rows, B, offsets=ex.row_offsets)
+            # the fields and the stage's counter: the scatter's span runs
+            # to the stage's end, so no stretch of the stage is unnamed
+            with maybe_span(tr, "gradient.scatter"):
+                gfs = _scatter_batch(grid, rows, B, offsets=ex.row_offsets)
+                n_crit = [sum(gf.n_critical().values()) for gf in gfs]
         dt = (time.perf_counter() - t0) / B
-        for state, report, gf in zip(states, reports, gfs):
+        for state, report, gf, n in zip(states, reports, gfs, n_crit):
             rep = report.child("gradient")
             rep.seconds = dt
-            rep.count(n_critical=sum(gf.n_critical().values()),
-                      batch_size=B)
+            rep.count(n_critical=n, batch_size=B)
             state.gf = gf
 
         # per-request critical extraction + back-end

@@ -52,6 +52,7 @@ import numpy as np
 from repro.core import gradient as GR
 from repro.core.gradient import GradientField
 from repro.core.grid import Grid
+from repro.obs.trace import current_trace, maybe_span
 
 
 class UnknownBackendError(KeyError):
@@ -217,7 +218,8 @@ def _bucket_batch(B: int) -> int:
 
 
 def _rows_fn(grid: Grid, kernel: str) -> Callable:
-    """orders (B, nv) -> packed rows over the flattened batch.
+    """orders (B, nv) -> packed rows over the flattened batch, as host
+    arrays.
 
     The stencil gather and the per-vertex pairing are both vertex-local,
     so a batch of B same-shape fields is just a (B*nv)-vertex problem —
@@ -252,17 +254,26 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
     jfn = jax.jit(fn)
 
     def wrapped(orders):
-        orders = jnp.asarray(orders)
-        B = orders.shape[0]
-        Bp = _bucket_batch(B)
-        if Bp != B:
-            # all(-1) pad fields: every simplex fails the lower-star test,
-            # so the padded lanes retire after one loop iteration
-            pad = jnp.full((Bp - B, orders.shape[1]), -1, orders.dtype)
-            orders = jnp.concatenate([orders, pad])
-        rows = jfn(orders)
+        # the gradient stage's sub-spans: copy in, kernel, copy out, unpack
+        # (the scatter follows in PersistencePipeline._run_group)
+        tr = current_trace()
+        with maybe_span(tr, "gradient.h2d"):
+            orders = jnp.asarray(orders)
+            B = orders.shape[0]
+            Bp = _bucket_batch(B)
+            if Bp != B:
+                # all(-1) pad fields: every simplex fails the lower-star
+                # test, so the padded lanes retire after one loop iteration
+                pad = jnp.full((Bp - B, orders.shape[1]), -1, orders.dtype)
+                orders = jnp.concatenate([orders, pad])
+            orders = jax.block_until_ready(orders)
+        with maybe_span(tr, "gradient.kernel"):
+            rows = jax.block_until_ready(jfn(orders))
+        with maybe_span(tr, "gradient.d2h"):
+            rows = [np.asarray(r) for r in rows]
         if kernel == "pallas":
-            rows = host_rows(rows, grid.dims[1], grid.dims[0])
+            with maybe_span(tr, "gradient.unpack"):
+                rows = host_rows(rows, grid.dims[1], grid.dims[0])
         n = B * grid.nv
         return tuple(r[:n] for r in rows)
 
@@ -271,13 +282,12 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
 
 
 def _scatter_batch(grid: Grid, rows, B: int, offsets=None):
-    """Split flattened-batch packed rows back into B GradientFields.
+    """Split flattened-batch packed host rows (the rows program's output)
+    back into B GradientFields.
 
     Fully vectorized: one flat index-arithmetic scatter over all dims and
     all batch elements (see ``GR.scatter_results_batch``)."""
-    status, partner, vstat, vpart = (np.asarray(r) for r in rows)
-    return GR.scatter_results_batch(grid, status, partner, vstat, vpart,
-                                    B, offsets=offsets)
+    return GR.scatter_results_batch(grid, *rows, B, offsets=offsets)
 
 
 def _make_kernel_gradient(kernel: str) -> Callable:

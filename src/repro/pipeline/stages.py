@@ -26,7 +26,9 @@ the trace thread-locally, and reports created inside the activation
 window bind to it automatically).  The public shape (``name`` /
 ``seconds`` / ``counters`` / ``children``, ``flat()``, ``to_dict()``)
 is unchanged; the trace adds wall-clock timestamps and thread identity
-on top, exported via ``result.trace.to_perfetto(path)``.
+on top, exported via ``result.trace.to_perfetto(path)``.  Every stage
+is also a ``stage.<name>`` annotation on the ``jax.profiler`` timeline,
+traced or not.
 """
 
 from __future__ import annotations
@@ -38,8 +40,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from repro.obs import flight as _flight
-from repro.obs.trace import Trace, current_trace
+from repro.obs.trace import Trace, current_trace, maybe_span
 
 from repro.core.critical import CriticalInfo
 from repro.core.diagram import Diagram
@@ -90,27 +91,19 @@ class StageReport:
 
     @contextmanager
     def stage(self, name: str):
-        """Open (and time) a child stage (and its span, when traced)."""
+        """Open (and time) a child stage.  The interval is a
+        :func:`repro.obs.trace.maybe_span`: a span with the stage's
+        counters when traced, a flight-recorder event otherwise, and a
+        ``stage.<name>`` annotation on the profiler timeline on both."""
         r = self.child(name)
-        tr = self.trace
-        if tr is None:
-            # untraced runs still feed the always-on flight recorder so a
-            # post-mortem dump shows which stage the process died in
-            t0 = time.perf_counter()
-            try:
-                yield r
-            finally:
-                dt = time.perf_counter() - t0
-                r.seconds += dt
-                _flight.record_event(name, t0, dt, r.counters or None)
-            return
-        with tr.span(name) as sp:
+        with maybe_span(self.trace, name) as sp:
             t0 = time.perf_counter()
             try:
                 yield r
             finally:
                 r.seconds += time.perf_counter() - t0
-                sp.args.update(r.counters)
+                if sp is not None:
+                    sp.args.update(r.counters)
 
     def count(self, **counters) -> None:
         for k, v in counters.items():

@@ -47,14 +47,24 @@ class TestTrace:
         assert inner.ts + inner.dur <= outer.ts + outer.dur
         assert inner.dur >= 0.001
 
-    def test_complete_records_measured_interval(self):
-        tr = Trace()
-        t0 = time.perf_counter()
-        time.sleep(0.002)
-        sp = tr.complete("round", t0, round=3)
-        assert sp.dur >= 0.002
-        assert sp.args == {"round": 3}
-        assert tr.events() == [sp]
+    @pytest.mark.parametrize("n", [8, 16])
+    def test_d1_round_spans_nest_one_per_round(self, n):
+        """D1 rounds are ``with`` spans: one per round on both D1 paths
+        (8^3 takes the burst path, 16^3 the wavefront), each inside the
+        ``d1`` stage span, and the exported timeline stays well nested."""
+        g = Grid.of(n, n, n)
+        res = PersistencePipeline(backend="jax").run(TopoRequest(
+            field=make_field("random", (n, n, n), seed=1), grid=g,
+            trace=True))
+        evs = res.trace.events()
+        rounds = [e for e in evs if e.name == "d1_round"]
+        (d1,) = [e for e in evs if e.name == "d1"]
+        assert len(rounds) == res.stats["d1_rounds"] > 0
+        assert [e.args["round"] for e in rounds] == \
+            list(range(1, len(rounds) + 1))
+        assert all(d1.ts <= e.ts and e.ts + e.dur <= d1.ts + d1.dur
+                   for e in rounds)
+        validate_trace_events(res.trace.to_dict())
 
     def test_instant_marker(self):
         tr = Trace()
@@ -378,6 +388,117 @@ class TestTracedPipeline:
         for required in ("chunk_load", "chunk_compute", "halo_publish",
                          "halo_recv"):
             assert required in span_names, span_names
+
+
+# --------------------------------------------------------------------------
+# profiler timeline: every span is also a ``stage.<name>`` annotation
+# --------------------------------------------------------------------------
+
+def _profiled(log_dir, fn):
+    """Run ``fn`` in a ``jax.profiler`` session; returns its result and
+    the ``stage.*`` host events as ``(name, start_ns, end_ns)``."""
+    import glob
+    import os
+
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    spans = [(e.name, e.start_ns, e.end_ns)
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("stage.")]
+    return out, spans
+
+
+def _run_profiled(log_dir, backend, n):
+    """One untraced diagram of a random n^3 field under the profiler
+    (after an unprofiled run that compiles)."""
+    g = Grid.of(n, n, n)
+    req = TopoRequest(field=make_field("random", (n, n, n), seed=1),
+                      grid=g)
+    pipe = PersistencePipeline(backend=backend)
+    pipe.run(req)
+    return _profiled(log_dir, lambda: pipe.run(req))
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """``jax`` backend: 8^3 runs the burst D1 path, 16^3 the wavefront."""
+    return {n: _run_profiled(tmp_path_factory.mktemp(f"prof{n}"), "jax", n)
+            for n in (8, 16)}
+
+
+def _named(spans, name):
+    return [s for s in spans if s[0] == name]
+
+
+def _inside(span, outers):
+    return any(o[1] <= span[1] and span[2] <= o[2] for o in outers)
+
+
+class TestProfilerTimeline:
+    def test_every_stage_is_on_the_profiler_timeline(self, profiled):
+        res, spans = profiled[8]
+        names = {s[0] for s in spans}
+        for stage in ("order", "gradient", "extract_sort", "d0", "d_top",
+                      "d1"):
+            assert len(_named(spans, "stage." + stage)) == 1, names
+        assert res.trace is None            # no Trace was asked for
+
+    @pytest.mark.parametrize("sub", ["h2d", "kernel", "d2h", "scatter"])
+    def test_gradient_sub_spans_nest(self, profiled, sub):
+        _, spans = profiled[8]
+        inner = _named(spans, "stage.gradient." + sub)
+        assert len(inner) == 1
+        assert _inside(inner[0], _named(spans, "stage.gradient"))
+
+    @pytest.mark.parametrize("sub", ["critical", "edge_keys", "rank"])
+    def test_extract_sub_spans_nest(self, profiled, sub):
+        _, spans = profiled[8]
+        inner = _named(spans, "stage.extract_sort." + sub)
+        assert len(inner) == 1
+        assert _inside(inner[0], _named(spans, "stage.extract_sort"))
+
+    def test_d0_rounds_run_under_d0_and_d_top(self, profiled):
+        _, spans = profiled[8]
+        rounds = _named(spans, "stage.d0_round")
+        d0, dtop = _named(spans, "stage.d0"), _named(spans, "stage.d_top")
+        in_d0 = [r for r in rounds if _inside(r, d0)]
+        in_dtop = [r for r in rounds if _inside(r, dtop)]
+        assert in_d0 and in_dtop
+        assert len(in_d0) + len(in_dtop) == len(rounds)
+
+    @pytest.mark.parametrize("n", [8, 16])
+    def test_d1_round_spans_match_the_counter(self, profiled, n):
+        res, spans = profiled[n]
+        rounds = _named(spans, "stage.d1_round")
+        assert len(rounds) == res.stats["d1_rounds"] > 0
+        assert all(_inside(r, _named(spans, "stage.d1")) for r in rounds)
+
+    def test_kill_switch_emits_no_annotation(self, tmp_path):
+        set_enabled(False)
+        try:
+            res, spans = _run_profiled(tmp_path, "jax", 6)
+        finally:
+            set_enabled(True)
+        assert res.diagram is not None
+        assert spans == []
+
+    def test_pallas_unpack_nests_in_gradient(self, tmp_path):
+        _, spans = _run_profiled(tmp_path, "pallas", 4)
+        grad = _named(spans, "stage.gradient")
+        for sub in ("h2d", "kernel", "d2h", "unpack", "scatter"):
+            inner = _named(spans, "stage.gradient." + sub)
+            assert len(inner) == 1 and _inside(inner[0], grad), sub
 
 
 # --------------------------------------------------------------------------
