@@ -311,9 +311,7 @@ def sid_dtype(grid: Grid, k: int):
 
 def scatter_results_batch(grid: Grid, status: np.ndarray, partner: np.ndarray,
                           vstatus: np.ndarray, vpartner: np.ndarray,
-                          B: int = 1,
-                          offsets: Optional[Dict[int, np.ndarray]] = None,
-                          ) -> List[GradientField]:
+                          B: int = 1) -> List[GradientField]:
     """Turn packed rows of B stacked same-grid fields into GradientFields.
 
     status/partner are (B*nv, 74), vstatus/vpartner (B*nv,).  All dims and
@@ -321,10 +319,13 @@ def scatter_results_batch(grid: Grid, status: np.ndarray, partner: np.ndarray,
     row->sid offset tables — the only Python loop is over the <= 3 simplex
     dimensions.  Pair/crit arrays are int32 whenever the sid space fits
     (it always does below ~180M vertices), halving gradient-field memory.
+    On the fused kernel the device builds the same arrays
+    (``kernels.lower_star.fields_from_words``); this scatter is their
+    oracle and serves the other kernels.
     """
     nv = grid.nv
     d = grid.dim
-    off = row_sid_offsets(grid) if offsets is None else offsets
+    off = row_sid_offsets(grid)
     N = B * nv
 
     space = {k: grid.sid_space(k) for k in range(d + 1)}

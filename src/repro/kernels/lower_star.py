@@ -16,8 +16,12 @@ scatter-style updates are selects.  Both kernels share this core
 (:func:`_pair_planes`) and write their results as 19 int32 words per
 vertex: 4 row bytes per word, the 74 row bytes followed by the vertex
 byte (``vstat`` in the status words, ``vpart`` in the partner words).
-:func:`host_rows` (or, inside a device program, :func:`_device_rows`)
-turns the words back into the (n, 74) int8 rows.
+On the pipeline's fused path :func:`fields_from_words` goes on, in the
+same device program, from the words to the dense gradient fields in the
+flat sid layout (static shifts and selects, no scatter), so only the
+fields reach the host.  :func:`host_rows` (or, inside a device program,
+:func:`_device_rows`) turns the words back into the (n, 74) int8 rows
+for the host scatter, which stays the oracle.
 
 1. **Fused kernel** (:func:`fused_lower_star_gradient_pallas`) — the
    production front-end.  The grid is (batch, z-plane, y-tile).  Each step
@@ -385,6 +389,152 @@ def _device_rows(words, ny: int, nx: int):
     st, pt = (jnp.moveaxis(_split_bytes(w[..., :ny, :nx], 2), 2, -1)
               .reshape(-1, R + 1) for w in words)
     return _split_rows(st, pt)
+
+
+def device_fields_fit(grid) -> bool:
+    """Whether :func:`fields_from_words` can build ``grid``'s fields: every
+    sid space below 2**31, so every pair array is int32."""
+    return max(grid.sid_space(k) for k in range(grid.dim + 1)) < 2 ** 31
+
+
+def _byte(w, r: int):
+    """Row byte ``r`` of (…, WORDS, y, x) words as a sign-extended int32
+    (…, y, x) plane (the shifts of :func:`_split_bytes`)."""
+    return (w[:, :, r // 4] << _I32(24 - 8 * (r % 4))) >> _I32(24)
+
+
+def _shift(plane, c):
+    """``plane[b, z + cz, y + cy, x + cx]`` for a (B, z, y, x) plane and
+    c in {0, 1}^3: a static slice and pad, 0 (NOT_L) beyond the grid."""
+    cx, cy, cz = (int(a) for a in c)
+    cut = plane[:, cz:, cy:, cx:]
+    return jnp.pad(cut, ((0, 0), (0, cz), (0, cy), (0, cx)))
+
+
+def _lookup(idx, table):
+    """``table[idx]`` for a tiny static table, as a select chain (0 where
+    idx is out of range): no gather."""
+    out = jnp.zeros(idx.shape, jnp.int32)
+    for q, v in enumerate(table):
+        out = jnp.where(idx == q, _I32(int(v)), out)
+    return out
+
+
+def _interleave(planes):
+    """T same-shape int32 or bool planes -> (rows, 128 * T) whose flat
+    order holds plane t's element i at ``i * T + t`` (the flat sid layout
+    ``sid = vid * T + t``), the tail past ``T * planes[0].size`` padding.
+
+    The interleave is a lane permutation, done on the MXU: 128 elements
+    of each plane side by side, times a 0/1 matrix.  Each output takes
+    exactly one product, so it is exact on bytes in bfloat16 with float32
+    sums; an int32 plane goes through as its 4 bytes (of value + 1, so
+    -1 reads 0).  A minor axis of T would pad to 128 lanes."""
+    T, n = len(planes), planes[0].size
+    pad = _round_up(n, LANES) - n
+    x = jnp.concatenate([jnp.pad(p.reshape(-1), (0, pad)).reshape(-1, LANES)
+                         for p in planes], axis=1)    # lane t * 128 + i
+    src = jax.lax.broadcasted_iota(jnp.int32, (T * LANES,) * 2, 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (T * LANES,) * 2, 1)
+    perm = (dst == (src % LANES) * T + src // LANES).astype(jnp.bfloat16)
+
+    def permute(v):
+        return jnp.dot(v.astype(jnp.bfloat16), perm,
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    if x.dtype == jnp.bool_:
+        return permute(x) > 0
+    u = x + _I32(1)
+    out = sum(permute((u >> _I32(8 * b)) & _I32(0xFF)) << _I32(8 * b)
+              for b in range(4))
+    return out - _I32(1)
+
+
+def fields_from_words(words, grid):
+    """The dense gradient fields of a batch, built on the device from
+    :func:`_fused_call` words: the device twin of
+    ``core.gradient.scatter_results_batch`` over the same rows.
+
+    A k-simplex ``(base, t)`` is written by exactly one row: the row
+    ``ROW_OFF[k] + t * (k + 1) + j`` of its order-maximal vertex
+    ``base + c_j`` (``c_j = STAR[k][t * (k + 1) + j, 1:]`` in {0, 1}^3), and
+    at its other vertices that row is NOT_L.  So every output is a select
+    over the k + 1 row planes shifted by a static ``c_j``: no scatter, and
+    no gather over the vertex axis.  The words are cropped to the grid
+    before any shift (a padded lane has order -1 and reads CRIT), and
+    reads beyond the grid are NOT_L, which gives -1 / False.
+
+    Returns ``(pair_up, pair_down, crit, n_critical)``: dicts of int32
+    pair and bool flag arrays (:func:`device_fields_fit` must hold) whose
+    first ``B * sid_space(k)`` elements, in C order, are the B fields'
+    arrays in the host's flat sid layout, back to back; and the (B,)
+    int32 count of critical simplices of each field.
+    """
+    nx, ny, nz = grid.dims
+    st, pt = (w[..., :ny, :nx] for w in words)
+    d = grid.dim
+    off = GR.row_sid_offsets(grid)
+    vid = (jax.lax.broadcasted_iota(jnp.int32, (nz, ny, nx), 2)
+           + _I32(nx) * (jax.lax.broadcasted_iota(jnp.int32, (nz, ny, nx), 1)
+                         + _I32(ny) * jax.lax.broadcasted_iota(
+                             jnp.int32, (nz, ny, nx), 0)))
+
+    def lin(c):
+        return int(c[0]) + nx * (int(c[1]) + ny * int(c[2]))
+
+    vs, vp = _byte(st, R), _byte(pt, R)
+    crit = {0: vs == CRIT}
+    pair_up, pair_down = {}, {}
+    if d >= 1:
+        pair_up[0] = jnp.where(vs == TAIL, vid * _I32(G.NTYPES[1])
+                               + _lookup(vp, off[1]), _I32(-1))
+    n_crit = crit[0].sum(axis=(1, 2, 3), dtype=jnp.int32)
+
+    def paired(sel, s, p, c, role):
+        """Fold row plane (s, p), read at shift c, into the selection of
+        the rows whose status is ``role``: (any, partner, lin(c))."""
+        hit = s == role
+        has, part, sh = sel
+        return (has | hit, jnp.where(hit, p, part),
+                jnp.where(hit, _I32(lin(c)), sh))
+
+    zero, none = jnp.zeros(vs.shape, jnp.int32), jnp.zeros(vs.shape, bool)
+    for k in range(1, d + 1):
+        T = G.NTYPES[k]
+        ups, downs, crits = [], [], []
+        for t in range(T):
+            head, tail, is_crit = (none, zero, zero), (none, zero, zero), none
+            for j in range(k + 1):
+                rl = t * (k + 1) + j
+                c = G.STAR[k][rl, 1:]
+                s = _shift(_byte(st, GR.ROW_OFF[k] + rl), c)
+                p = _shift(_byte(pt, GR.ROW_OFF[k] + rl), c)
+                is_crit = is_crit | (s == CRIT)
+                head = paired(head, s, p, c, HEAD)
+                if k < d:
+                    tail = paired(tail, s, p, c, TAIL)
+            has, p, sh = head
+            v = vid + sh                        # the row's vertex
+            if k == 1:      # an edge's head pairs with its vertex
+                downs.append(jnp.where(has, v, _I32(-1)))
+            else:
+                downs.append(jnp.where(
+                    has, v * _I32(G.NTYPES[k - 1])
+                    + _lookup(p - _I32(GR.ROW_OFF[k - 1]), off[k - 1]),
+                    _I32(-1)))
+            if k < d:
+                has, p, sh = tail
+                ups.append(jnp.where(
+                    has, (vid + sh) * _I32(G.NTYPES[k + 1])
+                    + _lookup(p - _I32(GR.ROW_OFF[k + 1]), off[k + 1]),
+                    _I32(-1)))
+            crits.append(is_crit)
+            n_crit = n_crit + is_crit.sum(axis=(1, 2, 3), dtype=jnp.int32)
+        pair_down[k] = _interleave(downs)
+        crit[k] = _interleave(crits)
+        if k < d:
+            pair_up[k] = _interleave(ups)
+    return pair_up, pair_down, crit, n_crit
 
 
 def host_rows(words, ny: int, nx: int):

@@ -15,7 +15,7 @@ jax:
 ``lower`` resolves the request against the pipeline defaults into an
 inspectable, hashable :class:`~repro.pipeline.plan.Plan` (backend,
 engines, stage chain, streamed/in-memory decomposition); ``compile``
-binds the compiled batched-rows program and scatter offset tables via
+binds the compiled batched-rows program (ranks to gradient fields) via
 the shared, evictable :class:`~repro.pipeline.plan.PlanCache` (one
 compile per ``(dims, backend, n_blocks)`` across repeated and batched
 requests).  Results are queryable :class:`~repro.pipeline.result
@@ -360,7 +360,6 @@ class PersistencePipeline:
                    ex: Executable) -> List[DiagramResult]:
         """Batched front-end: one compiled rows program over the stacked
         batch, then per-request back-ends."""
-        from .backends import _scatter_batch
         cfg = self._cfg(plan)
         grid = reqs[0].grid
         B = len(reqs)
@@ -373,22 +372,22 @@ class PersistencePipeline:
                 state.f = np.asarray(state.f).reshape(-1)
                 state.order = np.asarray(vertex_order(state.f))
 
-        # one batched gradient dispatch for the whole batch
+        # one batched gradient dispatch for the whole batch; the program
+        # returns the fields and their critical counts, built on the
+        # device (device_fields 1) or scattered on the host (0)
+        device_fields = ex.rows_program.device_fields
         tr = current_trace()
         t0 = time.perf_counter()
-        with maybe_span(tr, "gradient", batch_size=B):
-            orders = np.stack([s.order for s in states])
-            rows = ex.rows_program(orders)
-            # the fields and the stage's counter: the scatter's span runs
-            # to the stage's end, so no stretch of the stage is unnamed
-            with maybe_span(tr, "gradient.scatter"):
-                gfs = _scatter_batch(grid, rows, B, offsets=ex.row_offsets)
-                n_crit = [sum(gf.n_critical().values()) for gf in gfs]
+        with maybe_span(tr, "gradient", batch_size=B,
+                        device_fields=device_fields):
+            gfs, n_crit = ex.rows_program(
+                np.stack([s.order for s in states]))
         dt = (time.perf_counter() - t0) / B
         for state, report, gf, n in zip(states, reports, gfs, n_crit):
             rep = report.child("gradient")
             rep.seconds = dt
-            rep.count(n_critical=n, batch_size=B)
+            rep.count(n_critical=n, batch_size=B,
+                      device_fields=device_fields)
             state.gf = gf
 
         # per-request critical extraction + back-end
@@ -489,7 +488,7 @@ class PersistencePipeline:
 class _ProgramsView:
     """Mapping adapter exposing the shared PlanCache under the legacy
     ``pipe._programs`` keys: ``(dims, backend, n_blocks)`` -> rows
-    program, ``("row_offsets", dims)`` -> scatter offset tables."""
+    program."""
 
     def __init__(self, cache: PlanCache):
         self._cache = cache

@@ -6,11 +6,12 @@ backend bundles:
 
 - ``gradient(grid, order, *, n_blocks=1)`` -> :class:`GradientField`;
 - an optional *batched rows* program ``batched_rows(grid)`` returning a
-  compiled ``orders (B, nv) -> packed rows`` function; ``Plan.compile``
-  binds it through the shared :class:`~repro.pipeline.plan.PlanCache`
-  (one compile per ``(dims, backend, n_blocks)``) and
-  ``PersistencePipeline.run_batch`` uses it to amortize the
-  stencil-gather pre-pass over a batch of same-shape requests;
+  compiled ``orders (B, nv) -> (GradientFields, critical counts)``
+  function; ``Plan.compile`` binds it through the shared
+  :class:`~repro.pipeline.plan.PlanCache` (one compile per ``(dims,
+  backend, n_blocks)``) and ``PersistencePipeline.run_batch`` uses it to
+  amortize the stencil-gather pre-pass over a batch of same-shape
+  requests;
 - capability flags (``jittable`` / ``sharded`` / ``batched`` /
   ``fused`` / ``streamed``) that ``lower()`` and the serving layer use
   to pick execution strategies (a streamed plan requires ``streamed``).
@@ -83,7 +84,8 @@ class Backend:
     gradient: Callable[..., GradientField]
     caps: BackendCaps = field(default_factory=BackendCaps)
     description: str = ""
-    # optional: grid -> compiled fn(orders (B, nv) int64) -> packed rows
+    # optional: grid -> compiled fn(orders (B, nv) int64) -> (fields,
+    # critical counts); see _rows_fn
     batched_rows: Optional[Callable[[Grid], Callable]] = None
 
 
@@ -218,28 +220,37 @@ def _bucket_batch(B: int) -> int:
 
 
 def _rows_fn(grid: Grid, kernel: str) -> Callable:
-    """orders (B, nv) -> packed rows over the flattened batch, as host
-    arrays.
+    """orders (B, nv) -> the batch's B :class:`GradientField`s and their
+    critical-simplex counts.
 
     The stencil gather and the per-vertex pairing are both vertex-local,
     so a batch of B same-shape fields is just a (B*nv)-vertex problem —
     one compiled program, one dispatch.  The whole device program is
-    jitted for every kernel (the fused kernel's words are unpacked on the
-    host after it), and the batch dimension is bucket-padded with inert
-    all(-1) fields so nearby batch sizes share one compiled program.
+    jitted for every kernel, and the batch dimension is bucket-padded
+    with inert all(-1) fields so nearby batch sizes share one compiled
+    program.  On the fused kernel the program goes on from the kernel's
+    words to the dense fields themselves (``fields_from_words``), so the
+    host only cuts them into per-field views; the other kernels, and a
+    fused grid whose sids need int64, return packed rows that the host
+    scatters (``GR.scatter_results_batch``).  ``device_fields`` says
+    which: 1 when the fields come from the device.
     """
     import jax
     import jax.numpy as jnp
     from repro.kernels import ref as REF
-    from repro.kernels.lower_star import (fused_words, host_rows,
+    from repro.kernels.lower_star import (device_fields_fit,
+                                          fields_from_words, fused_words,
+                                          host_rows,
                                           lower_star_gradient_pallas)
+
+    on_device = kernel == "pallas" and device_fields_fit(grid)
 
     def fn(orders):  # (Bp, nv) rank fields
         if kernel == "pallas":
             # fused path: gather happens inside the kernel, the batch is a
-            # leading grid dimension — no (B*nv, 27) tensor materializes;
-            # the packed words are unpacked on the host
-            return fused_words(grid, orders)
+            # leading grid dimension — no (B*nv, 27) tensor materializes
+            words = fused_words(grid, orders)
+            return fields_from_words(words, grid) if on_device else words
         o = orders.astype(jnp.int32) if grid.nv < 2 ** 31 else orders
         nbrs = jax.vmap(
             lambda oo: GR.neighbor_orders(grid, oo, xp=jnp))(o)
@@ -254,8 +265,9 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
     jfn = jax.jit(fn)
 
     def wrapped(orders):
-        # the gradient stage's sub-spans: copy in, kernel, copy out, unpack
-        # (the scatter follows in PersistencePipeline._run_group)
+        # the gradient stage's sub-spans: copy in, device program, copy
+        # out, then the fields on the host (the unpack of the fused
+        # kernel's words only where the host scatters them)
         tr = current_trace()
         with maybe_span(tr, "gradient.h2d"):
             orders = jnp.asarray(orders)
@@ -268,26 +280,41 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
                 orders = jnp.concatenate([orders, pad])
             orders = jax.block_until_ready(orders)
         with maybe_span(tr, "gradient.kernel"):
-            rows = jax.block_until_ready(jfn(orders))
+            out = jax.block_until_ready(jfn(orders))
         with maybe_span(tr, "gradient.d2h"):
-            rows = [np.asarray(r) for r in rows]
+            out = jax.device_get(out)
+        if on_device:
+            with maybe_span(tr, "gradient.scatter"):
+                return _field_views(grid, out, B)
         if kernel == "pallas":
             with maybe_span(tr, "gradient.unpack"):
-                rows = host_rows(rows, grid.dims[1], grid.dims[0])
-        n = B * grid.nv
-        return tuple(r[:n] for r in rows)
+                out = host_rows(out, grid.dims[1], grid.dims[0])
+        with maybe_span(tr, "gradient.scatter"):
+            n = B * grid.nv
+            gfs = GR.scatter_results_batch(grid, *(r[:n] for r in out), B)
+            return gfs, [sum(gf.n_critical().values()) for gf in gfs]
 
     wrapped._jit = jfn  # compile-cache probe for the recompile tests
+    wrapped.device_fields = int(on_device)
     return wrapped
 
 
-def _scatter_batch(grid: Grid, rows, B: int, offsets=None):
-    """Split flattened-batch packed host rows (the rows program's output)
-    back into B GradientFields.
+def _field_views(grid: Grid, fields, B: int):
+    """Per-field views of ``fields_from_words`` output copied to the
+    host: each array's first ``B * sid_space(k)`` elements are the B
+    fields' flat sid arrays back to back (the rest is bucket padding)."""
+    pair_up, pair_down, crit, n_crit = fields
 
-    Fully vectorized: one flat index-arithmetic scatter over all dims and
-    all batch elements (see ``GR.scatter_results_batch``)."""
-    return GR.scatter_results_batch(grid, *rows, B, offsets=offsets)
+    def cut(arrays):
+        return {k: a.reshape(-1)[:B * grid.sid_space(k)].reshape(B, -1)
+                for k, a in arrays.items()}
+
+    up, down, cr = cut(pair_up), cut(pair_down), cut(crit)
+    gfs = [GradientField(grid, {k: a[b] for k, a in up.items()},
+                         {k: a[b] for k, a in down.items()},
+                         {k: a[b] for k, a in cr.items()})
+           for b in range(B)]
+    return gfs, [int(c) for c in n_crit[:B]]
 
 
 def _make_kernel_gradient(kernel: str) -> Callable:

@@ -8,10 +8,10 @@ the request does not ask for are dropped, e.g. ``homology_dims=(0,)``
 on a 3-D grid skips the D1 engine).  Plans are frozen, hashable, and
 inspectable (``describe()``) without touching field data.
 
-``Plan.compile()`` binds the compiled artifacts — the backend's batched
-packed-rows program and the per-grid row→sid scatter offset tables —
-through a shared, evictable :class:`PlanCache` (this replaces the
-ad-hoc per-pipeline ``_programs`` dict).  Compiled programs are keyed
+``Plan.compile()`` binds the compiled artifact — the backend's batched
+rows program, ranks to gradient fields — through a shared, evictable
+:class:`PlanCache` (this replaces the ad-hoc per-pipeline ``_programs``
+dict).  Compiled programs are keyed
 by ``(dims, backend, n_blocks)``: two plans differing only in result
 options or engine knobs share one compile, which is the compile-count
 contract the regression tests assert.
@@ -254,8 +254,8 @@ class Plan:
 
     def compile(self, cache: Optional[PlanCache] = None,
                 backend: Optional[Backend] = None) -> "Executable":
-        """Bind compiled artifacts (batched rows program + row→sid offset
-        tables) through ``cache`` (the shared default if None).
+        """Bind the compiled artifact (the batched rows program) through
+        ``cache`` (the shared default if None).
 
         ``backend`` overrides the registry lookup — the pipeline passes
         its own held instance so unregistered :class:`Backend` objects
@@ -286,28 +286,24 @@ class Plan:
                     if self.compile_key not in memo:
                         memo[self.compile_key] = be.batched_rows(grid)
                     rows_program = memo[self.compile_key]
-        from repro.core.gradient import row_sid_offsets
-        offsets = cache.get_or_build(("row_offsets", self.dims),
-                                     lambda: row_sid_offsets(grid))
         return Executable(plan=self, backend=be,
-                          rows_program=rows_program, row_offsets=offsets,
-                          cache=cache)
+                          rows_program=rows_program, cache=cache)
 
 
 @dataclass(frozen=True)
 class Executable:
     """A plan with its compiled artifacts bound, ready to execute.
 
-    ``rows_program`` is the backend's jitted ``orders (B, nv) -> packed
-    rows`` program (None for non-batch backends such as ``np`` /
-    ``shardmap``); ``row_offsets`` the per-grid row→sid scatter tables.
-    Both come out of the shared :class:`PlanCache`, so repeated and
-    batched requests of one ``(dims, backend, n_blocks)`` reuse a single
-    compile."""
+    ``rows_program`` is the backend's jitted ``orders (B, nv) ->
+    (GradientFields, critical counts)`` program (None for non-batch
+    backends such as ``np`` / ``shardmap``): on the fused kernel it
+    builds the fields on the device, on the others it scatters packed
+    rows on the host (``backends._rows_fn``).  It comes out of the shared
+    :class:`PlanCache`, so repeated and batched requests of one ``(dims,
+    backend, n_blocks)`` reuse a single compile."""
 
     plan: Plan
     backend: Backend
     rows_program: Optional[Callable] = None
-    row_offsets: object = None
     cache: PlanCache = field(default_factory=default_plan_cache, repr=False,
                              compare=False)

@@ -494,11 +494,16 @@ class TestProfilerTimeline:
         assert spans == []
 
     def test_pallas_unpack_nests_in_gradient(self, tmp_path):
-        _, spans = _run_profiled(tmp_path, "pallas", 4)
+        """The fused path builds the fields on the device: its gradient
+        stage has no host unpack, and the scatter span holds only the
+        per-field views."""
+        res, spans = _run_profiled(tmp_path, "pallas", 4)
         grad = _named(spans, "stage.gradient")
-        for sub in ("h2d", "kernel", "d2h", "unpack", "scatter"):
+        for sub in ("h2d", "kernel", "d2h", "scatter"):
             inner = _named(spans, "stage.gradient." + sub)
             assert len(inner) == 1 and _inside(inner[0], grad), sub
+        assert _named(spans, "stage.gradient.unpack") == []
+        assert res.stats["device_fields"] == 1
 
 
 # --------------------------------------------------------------------------
@@ -531,7 +536,8 @@ class TestServiceTelemetry:
         from repro.pipeline import PlanCache
         before = global_metrics().snapshot()
         cache = PlanCache()
-        pipe = PersistencePipeline(backend="np", plan_cache=cache)
+        # the rows program is the plan's one cached artifact (np has none)
+        pipe = PersistencePipeline(backend="jax", plan_cache=cache)
         dims = (4, 4, 4)
         g = Grid.of(*dims)
         req = TopoRequest(field=make_field("random", dims, seed=0), grid=g)

@@ -174,10 +174,9 @@ class TestLowerCompile:
                         for _ in range(3)])         # and a batch
         key = (g.dims, "jax", 1)
         assert cache.build_counts[key] == 1
-        assert cache.build_counts[("row_offsets", g.dims)] == 1
         st = cache.stats()
-        assert st["compiles"] == 2      # rows program + offset tables
-        assert st["hits"] >= 6
+        assert st["compiles"] == 1      # the rows program alone
+        assert st["hits"] >= 3      # every later lookup of it
 
     def test_plan_cache_builds_outside_lock(self):
         """A slow build of one key must not block lookups of other keys,

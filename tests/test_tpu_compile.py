@@ -38,13 +38,13 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, *shapes, sharding):
+def _compile(fn, *shapes, sharding, hbm_bytes=HBM_BYTES):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
-    assert used < HBM_BYTES, f"{used / 2 ** 30:.1f} GiB > one chip's HBM"
+    assert used < hbm_bytes, f"{used / 2 ** 30:.2f} GiB > {hbm_bytes:,} B"
     return compiled.as_text()
 
 
@@ -57,6 +57,21 @@ def test_fused_kernel_compiles(one_chip, shape):
     fn = jax.jit(lambda v: _fused_call(v, interpret=False))
     assert "tpu_custom_call" in _compile(fn, (shape, jnp.int32),
                                          sharding=one_chip)
+
+
+def test_fused_fields_program_compiles(one_chip):
+    """The fused rows program of one 128^3 volume, kernel and device
+    fields: no XLA scatter, and under 1.5 GB of device memory (no
+    lane-padded (..., T_k) intermediate)."""
+    from repro.core.grid import Grid
+    from repro.kernels.lower_star import _fused_call, fields_from_words
+    g = Grid.of(128, 128, 128)
+    fn = jax.jit(lambda v: fields_from_words(_fused_call(v, interpret=False),
+                                             g))
+    text = _compile(fn, ((1, 130, 128, 128), jnp.int32), sharding=one_chip,
+                    hbm_bytes=1.5e9)
+    assert "tpu_custom_call" in text
+    assert " scatter(" not in text
 
 
 def test_streamed_chunk_compiles(one_chip):
