@@ -38,7 +38,8 @@ import numpy as np
 from repro.core import gradient as GR
 from repro.core import grid as G
 from repro.kernels import ref as REF
-from repro.kernels.lower_star import (fused_rows_from_halo_volume,
+from repro.kernels.lower_star import (fields_from_words,
+                                      fused_rows_from_halo_volume, halo_words,
                                       lower_star_gradient_pallas)
 from repro.obs import flight as _flight
 from .order import rankfree_keys, sample_sort_ranks
@@ -288,46 +289,123 @@ def _rank_bound(cfg: FrontConfig) -> Optional[int]:
     return nx * ny * nz
 
 
+def halo_exchange(cfg: FrontConfig, r3):
+    """The ring neighbours' boundary rank planes of my z-slab (inside
+    shard_map): ``r3`` is (..., nz_local, ny, nx); returns ``(below,
+    above)``, each (..., 1, ny, nx) in ``r3``'s dtype: the last plane of
+    the slab below and the first plane of the slab above, -1 at the
+    global boundary.  Two one-plane ``ppermute``s under the ``halo``
+    scope."""
+    ax = cfg.axis_name
+    me = _axis_index(ax)
+    with jax.named_scope("halo"):
+        below = _ppshift(r3[..., -1:, :, :], ax, up=True)
+        above = _ppshift(r3[..., :1, :, :], ax, up=False)
+    fill = jnp.asarray(-1, r3.dtype)
+    below = jnp.where(me > 0, below, fill)
+    above = jnp.where(me < cfg.n_blocks - 1, above, fill)
+    return below, above
+
+
+def slab_words(cfg: FrontConfig, ranks):
+    """The fused kernel's words of my z-slab (inside shard_map).
+
+    ranks: (B, nv_local) int32 global vertex ranks of my slab for a batch
+    of B fields.  The exchanged planes are exactly the z halo the fused
+    kernel reads, so the kernel runs on the halo-extended slab directly;
+    no (nv_local, 27) neighbour tensor is built.  Returns the (status,
+    partner) words, each (B, nz_local, 19, ny8, nx128) int32."""
+    nx, ny, _ = cfg.dims
+    r3 = ranks.reshape(ranks.shape[0], cfg.nz_local, ny, nx)
+    below, above = halo_exchange(cfg, r3)
+    return halo_words(jnp.concatenate([below, r3, above], axis=1),
+                      rank_bound=_rank_bound(cfg))
+
+
+def slab_fields(cfg: FrontConfig, grid, ranks):
+    """The dense gradient fields of my z-slab, built on my chip (inside
+    shard_map): the sids whose base vertex lies in my slab, numbered
+    globally (``sid = vid * T_k + t``).
+
+    A simplex based in my last plane can have its top vertex, and so the
+    row that writes it, in the first plane of the slab above: that
+    plane's words come down the ring by one more one-plane ``ppermute``
+    (all NOT_L above the grid).  Sending 2 x 19 int32 words per vertex
+    of a plane costs microseconds on the interconnect, where a second
+    halo plane would make the kernel pair one more plane of the slab.
+
+    Returns ``fields_from_words``' ``(pair_up, pair_down, crit,
+    n_critical)`` for my slab, the (B,) critical counts summed over the
+    ring."""
+    nx, ny, _ = cfg.dims
+    ax = cfg.axis_name
+    words = slab_words(cfg, ranks)
+    with jax.named_scope("halo"):
+        ghost = [_ppshift(w[:, :1, :, :ny, :nx], ax, up=False)
+                 for w in words]
+    z0 = _axis_index(ax) * cfg.nz_local
+    pair_up, pair_down, crit, n_crit = fields_from_words(
+        words, grid, z0=z0, ghost=ghost)
+    with jax.named_scope("halo"):
+        n_crit = jax.lax.psum(n_crit, ax)
+    return pair_up, pair_down, crit, n_crit
+
+
+def slab_program(grid, mesh, *, fields: bool = True):
+    """The jitted ``shard_map`` of :func:`slab_fields` (or, with
+    ``fields=False``, :func:`slab_words`) over a one-axis ``mesh``: (B,
+    nv) int32 ranks, sharded on the vertex axis into z-slabs, to the
+    fields sharded on their leading axis and the replicated critical
+    counts (or the words, sharded on the z axis)."""
+    from jax.sharding import PartitionSpec as P
+    ax = mesh.axis_names[0]
+    cfg = FrontConfig(tuple(grid.dims), mesh.size, axis_name=ax)
+    cfg.nz_local  # eager divisibility check
+    slabs = P(None, ax)
+
+    def dev_fn(o):  # (B, nv_local) ranks of my slab
+        return slab_fields(cfg, grid, o) if fields else slab_words(cfg, o)
+
+    return jax.jit(jax.shard_map(
+        dev_fn, mesh=mesh, in_specs=slabs,
+        out_specs=(P(ax),) * 3 + (P(),) if fields else slabs,
+        check_vma=False))
+
+
 def halo_gradient(cfg: FrontConfig, ranks):
     """Halo-exchange the boundary rank planes with the ring neighbors and
-    run the lower-star gradient on the local slab (inside shard_map).
+    run the lower-star gradient on the local slab (inside shard_map):
+    the gradient step of :func:`front_device_fn` (``run_front``).  The
+    pipeline's ``shardmap`` backend runs :func:`slab_fields` instead.
 
-    ranks: (nv_local,) int64 global vertex ranks of my z-slab.
+    ranks: (nv_local,) global vertex ranks of my z-slab.
     Returns (nbrs, (status, partner, vstat, vpart)): the (nv_local, 27)
-    neighbor-order tensor and the packed gradient rows.
+    neighbor-order tensor, which the triplet-key extraction of
+    ``front_device_fn`` reads at the critical simplices, and the packed
+    gradient rows.
 
-    The one-plane ``ppermute`` exchange produces exactly the z-halo the
-    fused kernel's overlapping BlockSpecs expect, so the ``"fused"``
-    backend consumes the extended volume directly — the (nv, 27) tensor
-    is still built here because the triplet-key extraction downstream
-    reads neighbor orders at the critical simplices.
-
-    With ``cfg.overlap_comm`` the work is split so the collective hides
-    behind compute: the ``ppermute`` is issued first, the interior
-    planes ``[1, nz_local - 1)`` (whose 27-neighborhoods are purely
-    local) are processed from the un-extended slab, and only the two
-    boundary planes consume the received halo (via 3-plane sub-volumes).
-    The row functions are per-vertex maps, so the stitched result is
-    bit-identical to the monolithic path — but XLA's scheduler is now
-    free to run the interior gradient while the halo is in flight.
+    The ``"fused"`` backend runs the kernel on the halo-extended slab.
+    With ``cfg.overlap_comm`` the other kernels split the work so the
+    collective hides behind compute: the ``ppermute`` is issued first,
+    the interior planes ``[1, nz_local - 1)`` (whose 27-neighborhoods are
+    purely local) are processed from the un-extended slab, and only the
+    two boundary planes consume the received halo (via 3-plane
+    sub-volumes).  The row functions are per-vertex maps, so the
+    stitched result is bit-identical to the monolithic path — but XLA's
+    scheduler is now free to run the interior gradient while the halo is
+    in flight.
     """
     nx, ny, _ = cfg.dims
     nzl, plane, nvl = cfg.nz_local, cfg.plane, cfg.nv_local
-    ax = cfg.axis_name
-    me = _axis_index(ax)
-    nb = cfg.n_blocks
     r3 = ranks.reshape(nzl, ny, nx)
     # issue the collectives first: nothing below depends on them until
     # the boundary-plane stitches at the very end of the overlap path
-    below = _ppshift(r3[-1], ax, up=True)
-    above = _ppshift(r3[0], ax, up=False)
-    below = jnp.where(me > 0, below, jnp.int64(-1))
-    above = jnp.where(me < nb - 1, above, jnp.int64(-1))
+    below, above = halo_exchange(cfg, r3)
     from repro.core.grid import Grid
     if not cfg.overlap_comm or nzl < 3 or cfg.gradient_backend == "fused":
         # monolithic path: the fused kernel wants the whole halo volume,
         # and slabs under 3 planes have no comm-free interior
-        ext = jnp.concatenate([below[None], r3, above[None]], axis=0)
+        ext = jnp.concatenate([below, r3, above], axis=0)
         eg = Grid.of(nx, ny, nzl + 2)
         nbrs_ext = GR.neighbor_orders(eg, ext.reshape(-1), xp=jnp)
         nbrs = nbrs_ext.reshape(nzl + 2, plane, 27)[1:-1].reshape(nvl, 27)
@@ -347,9 +425,9 @@ def halo_gradient(cfg: FrontConfig, ranks):
             .reshape(3, plane, 27)[1]
         return nb_, _gradient_rows(cfg, nb_, own)
 
-    nbrs_lo, rows_lo = boundary(jnp.stack([below, r3[0], r3[1]]),
+    nbrs_lo, rows_lo = boundary(jnp.concatenate([below, r3[:2]]),
                                 ranks[:plane])
-    nbrs_hi, rows_hi = boundary(jnp.stack([r3[-2], r3[-1], above]),
+    nbrs_hi, rows_hi = boundary(jnp.concatenate([r3[-2:], above]),
                                 ranks[nvl - plane:])
 
     nbrs = jnp.concatenate([nbrs_lo, nbrs_int, nbrs_hi], axis=0)

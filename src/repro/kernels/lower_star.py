@@ -450,7 +450,7 @@ def _interleave(planes):
     return out - _I32(1)
 
 
-def fields_from_words(words, grid):
+def fields_from_words(words, grid, *, z0=None, ghost=None):
     """The dense gradient fields of a batch, built on the device from
     :func:`_fused_call` words: the device twin of
     ``core.gradient.scatter_results_batch`` over the same rows.
@@ -464,20 +464,41 @@ def fields_from_words(words, grid):
     before any shift (a padded lane has order -1 and reads CRIT), and
     reads beyond the grid are NOT_L, which gives -1 / False.
 
+    A z-slab of ``grid`` (the shardmap front-end) passes its words, the
+    global plane index ``z0`` of its first plane (traced) and ``ghost``:
+    the words of the next slab's first plane, all NOT_L (0) above the
+    grid.  The fields then cover the sids whose base vertex lies in the
+    slab, numbered globally; the rows of a simplex whose top vertex lies
+    one plane up come from the ghost plane.
+
     Returns ``(pair_up, pair_down, crit, n_critical)``: dicts of int32
     pair and bool flag arrays (:func:`device_fields_fit` must hold) whose
-    first ``B * sid_space(k)`` elements, in C order, are the B fields'
-    arrays in the host's flat sid layout, back to back; and the (B,)
-    int32 count of critical simplices of each field.
+    first ``B * sid_space(k)`` elements (a slab: ``B * nz_slab * ny * nx
+    * T_k``), in C order, are the B fields' arrays in the host's flat sid
+    layout, back to back; and the (B,) int32 count of critical simplices
+    of each field.
     """
-    nx, ny, nz = grid.dims
+    nx, ny, _ = grid.dims
     st, pt = (w[..., :ny, :nx] for w in words)
+    nz = st.shape[1]
     d = grid.dim
     off = GR.row_sid_offsets(grid)
     vid = (jax.lax.broadcasted_iota(jnp.int32, (nz, ny, nx), 2)
            + _I32(nx) * (jax.lax.broadcasted_iota(jnp.int32, (nz, ny, nx), 1)
                          + _I32(ny) * jax.lax.broadcasted_iota(
                              jnp.int32, (nz, ny, nx), 0)))
+    if z0 is not None:
+        vid = vid + jnp.asarray(z0, jnp.int32) * _I32(nx * ny)
+    if ghost is None:
+        up_st, up_pt = st, pt
+    else:
+        up_st, up_pt = (jnp.concatenate([w, g[..., :ny, :nx]], axis=1)
+                        for w, g in zip((st, pt), ghost))
+
+    def row_plane(w, r, c):
+        """Row byte ``r`` read at shift ``c``, over the slab's planes."""
+        p = _shift(_byte(w, r), c)
+        return p if ghost is None else p[:, :nz]
 
     def lin(c):
         return int(c[0]) + nx * (int(c[1]) + ny * int(c[2]))
@@ -507,8 +528,8 @@ def fields_from_words(words, grid):
             for j in range(k + 1):
                 rl = t * (k + 1) + j
                 c = G.STAR[k][rl, 1:]
-                s = _shift(_byte(st, GR.ROW_OFF[k] + rl), c)
-                p = _shift(_byte(pt, GR.ROW_OFF[k] + rl), c)
+                s = row_plane(up_st, GR.ROW_OFF[k] + rl, c)
+                p = row_plane(up_pt, GR.ROW_OFF[k] + rl, c)
                 is_crit = is_crit | (s == CRIT)
                 head = paired(head, s, p, c, HEAD)
                 if k < d:
@@ -579,16 +600,22 @@ def fused_lower_star_gradient_pallas(grid, orders, *,
     return host_rows(fused_words(grid, orders, rank_bound=rank_bound), ny, nx)
 
 
-def fused_rows_from_halo_volume(ext, *, rank_bound: int | None = None):
-    """Fused kernel over a z-slab whose halo planes were exchanged already.
+def halo_words(ext, *, rank_bound: int | None = None):
+    """Fused kernel words over z-slabs whose halo planes were exchanged
+    already.
 
-    ext: (nz_local+2, ny, nx) rank volume; the first/last z-planes are the
-    ghost planes received from the ring neighbors (-1 at the global
+    ext: (B, nz_local+2, ny, nx) rank volumes; the first/last z-planes are
+    the ghost planes received from the ring neighbors (-1 at the global
     boundary) — exactly the z halo the fused kernel reads, so the
-    shardmap front-end feeds the kernel directly.  Returns packed rows
-    for the nz_local*ny*nx owned vertices.
-    """
+    shardmap front-end feeds the kernel directly.  Returns the (status,
+    partner) words of the owned vertices, as :func:`_fused_call`."""
+    return _fused_call(_as_int32(jnp.asarray(ext), rank_bound),
+                       interpret=interpret_mode())
+
+
+def fused_rows_from_halo_volume(ext, *, rank_bound: int | None = None):
+    """Packed rows of :func:`halo_words` over one (nz_local+2, ny, nx)
+    halo-extended slab, for the nz_local*ny*nx owned vertices."""
     _, ny, nx = ext.shape
-    ext = _as_int32(jnp.asarray(ext), rank_bound)
-    return _device_rows(_fused_call(ext[None], interpret=interpret_mode()),
-                        ny, nx)
+    return _device_rows(halo_words(jnp.asarray(ext)[None],
+                                   rank_bound=rank_bound), ny, nx)

@@ -374,20 +374,19 @@ class PersistencePipeline:
 
         # one batched gradient dispatch for the whole batch; the program
         # returns the fields and their critical counts, built on the
-        # device (device_fields 1) or scattered on the host (0)
-        device_fields = ex.rows_program.device_fields
+        # device (device_fields 1) or scattered on the host (0); a
+        # sharded program also counts n_blocks and halo_bytes
+        counters = ex.rows_program.counters
         tr = current_trace()
         t0 = time.perf_counter()
-        with maybe_span(tr, "gradient", batch_size=B,
-                        device_fields=device_fields):
+        with maybe_span(tr, "gradient", batch_size=B, **counters):
             gfs, n_crit = ex.rows_program(
                 np.stack([s.order for s in states]))
         dt = (time.perf_counter() - t0) / B
         for state, report, gf, n in zip(states, reports, gfs, n_crit):
             rep = report.child("gradient")
             rep.seconds = dt
-            rep.count(n_critical=n, batch_size=B,
-                      device_fields=device_fields)
+            rep.count(n_critical=n, batch_size=B, **counters)
             state.gf = gf
 
         # per-request critical extraction + back-end

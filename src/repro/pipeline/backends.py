@@ -5,9 +5,9 @@ to live in ``compute_dms`` / ``compute_ddms_sim`` / ``kernels.ops``.  A
 backend bundles:
 
 - ``gradient(grid, order, *, n_blocks=1)`` -> :class:`GradientField`;
-- an optional *batched rows* program ``batched_rows(grid)`` returning a
-  compiled ``orders (B, nv) -> (GradientFields, critical counts)``
-  function; ``Plan.compile`` binds it through the shared
+- an optional *batched rows* program ``batched_rows(grid, n_blocks)``
+  returning a compiled ``orders (B, nv) -> (GradientFields, critical
+  counts)`` function; ``Plan.compile`` binds it through the shared
   :class:`~repro.pipeline.plan.PlanCache` (one compile per ``(dims,
   backend, n_blocks)``) and ``PersistencePipeline.run_batch`` uses it to
   amortize the stencil-gather pre-pass over a batch of same-shape
@@ -27,10 +27,13 @@ Registered backends:
   around each tile (Mosaic-compiled on a TPU, interpreted elsewhere);
 - ``pallas_prepass`` — the original im2col pre-pass + vertex-tiled
   Pallas kernel, kept as a fallback and cross-check;
-- ``shardmap``       — the device-level z-slab front-end: ``shard_map``
-  over a mesh ring with one-plane ``ppermute`` halo exchange of ranks,
-  the same program ``repro.distributed.shardmap_pipeline`` runs at
-  scale.
+- ``shardmap``       — the z-slab front-end over ``n_blocks`` devices:
+  one cached ``shard_map`` rows program per ``(dims, n_blocks)`` in
+  which each device exchanges int32 rank planes with its ring
+  neighbours (``ppermute``), runs the fused kernel on its slab and
+  builds its slab's fields, the next slab's first plane of words
+  arriving as a ghost plane (``repro.distributed.shardmap_pipeline
+  .slab_fields``).
 
 Batched rows programs are jitted end to end and their *batch dimension
 is bucket-padded* (see ``_bucket_batch``), so nearby batch sizes reuse
@@ -84,9 +87,9 @@ class Backend:
     gradient: Callable[..., GradientField]
     caps: BackendCaps = field(default_factory=BackendCaps)
     description: str = ""
-    # optional: grid -> compiled fn(orders (B, nv) int64) -> (fields,
-    # critical counts); see _rows_fn
-    batched_rows: Optional[Callable[[Grid], Callable]] = None
+    # optional: (grid, n_blocks) -> compiled fn(orders (B, nv) int64) ->
+    # (fields, critical counts); see _rows_fn, _shardmap_rows_fn
+    batched_rows: Optional[Callable[[Grid, int], Callable]] = None
 
 
 _REGISTRY: Dict[str, Backend] = {}
@@ -240,7 +243,6 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
     from repro.kernels import ref as REF
     from repro.kernels.lower_star import (device_fields_fit,
                                           fields_from_words, fused_words,
-                                          host_rows,
                                           lower_star_gradient_pallas)
 
     on_device = kernel == "pallas" and device_fields_fit(grid)
@@ -262,31 +264,44 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
         return REF.lower_star_gradient_jnp(flat_nbrs, flat_ov,
                                            rank_bound=grid.nv)
 
-    jfn = jax.jit(fn)
+    return _rows_program(grid, jax.jit(fn), jnp.asarray,
+                         on_device=on_device, words=kernel == "pallas")
+
+
+def _rows_program(grid: Grid, jfn, put, *, on_device: bool, words: bool,
+                  n_blocks: int = 1, counters: Optional[dict] = None
+                  ) -> Callable:
+    """The host side of a jitted rows program: pad the (B, nv) orders to
+    the batch bucket, ``put`` them on the device, run ``jfn``, copy its
+    outputs to the host, then cut the device's fields into per-field
+    views (``on_device``), or scatter its packed rows (``words``: the
+    fused kernel's words, unpacked first).  Each step is a sub-span of
+    the gradient stage.  ``counters`` (with ``device_fields``) go on the
+    stage's report and span."""
+    import jax
+    from repro.kernels.lower_star import host_rows
 
     def wrapped(orders):
-        # the gradient stage's sub-spans: copy in, device program, copy
-        # out, then the fields on the host (the unpack of the fused
-        # kernel's words only where the host scatters them)
         tr = current_trace()
+        orders = np.asarray(orders)
+        B = orders.shape[0]
         with maybe_span(tr, "gradient.h2d"):
-            orders = jnp.asarray(orders)
-            B = orders.shape[0]
             Bp = _bucket_batch(B)
             if Bp != B:
                 # all(-1) pad fields: every simplex fails the lower-star
-                # test, so the padded lanes retire after one loop iteration
-                pad = jnp.full((Bp - B, orders.shape[1]), -1, orders.dtype)
-                orders = jnp.concatenate([orders, pad])
-            orders = jax.block_until_ready(orders)
+                # test, so the padded lanes retire after one loop
+                # iteration
+                orders = np.concatenate([orders, np.full(
+                    (Bp - B, orders.shape[1]), -1, orders.dtype)])
+            orders = jax.block_until_ready(put(orders))
         with maybe_span(tr, "gradient.kernel"):
             out = jax.block_until_ready(jfn(orders))
         with maybe_span(tr, "gradient.d2h"):
             out = jax.device_get(out)
         if on_device:
             with maybe_span(tr, "gradient.scatter"):
-                return _field_views(grid, out, B)
-        if kernel == "pallas":
+                return _field_views(grid, out, B, n_blocks)
+        if words:
             with maybe_span(tr, "gradient.unpack"):
                 out = host_rows(out, grid.dims[1], grid.dims[0])
         with maybe_span(tr, "gradient.scatter"):
@@ -295,19 +310,26 @@ def _rows_fn(grid: Grid, kernel: str) -> Callable:
             return gfs, [sum(gf.n_critical().values()) for gf in gfs]
 
     wrapped._jit = jfn  # compile-cache probe for the recompile tests
-    wrapped.device_fields = int(on_device)
+    wrapped.counters = dict(device_fields=int(on_device), **(counters or {}))
     return wrapped
 
 
-def _field_views(grid: Grid, fields, B: int):
+def _field_views(grid: Grid, fields, B: int, n_blocks: int = 1):
     """Per-field views of ``fields_from_words`` output copied to the
     host: each array's first ``B * sid_space(k)`` elements are the B
-    fields' flat sid arrays back to back (the rest is bucket padding)."""
+    fields' flat sid arrays back to back (the rest is bucket padding).
+    Slab-sharded output holds ``n_blocks`` such arrays of one z-slab
+    each, end to end; a field's slabs are contiguous in its sid layout,
+    so with one field and no padding every view is still a view."""
     pair_up, pair_down, crit, n_crit = fields
 
     def cut(arrays):
-        return {k: a.reshape(-1)[:B * grid.sid_space(k)].reshape(B, -1)
-                for k, a in arrays.items()}
+        out = {}
+        for k, a in arrays.items():
+            n = grid.sid_space(k) // n_blocks
+            a = a.reshape(n_blocks, -1)[:, :B * n].reshape(n_blocks, B, n)
+            out[k] = a.swapaxes(0, 1).reshape(B, -1)
+        return out
 
     up, down, cr = cut(pair_up), cut(pair_down), cut(crit)
     gfs = [GradientField(grid, {k: a[b] for k, a in up.items()},
@@ -327,42 +349,67 @@ def _make_kernel_gradient(kernel: str) -> Callable:
 # shardmap — device-level z-slab front-end (mesh ring + halo exchange)
 # --------------------------------------------------------------------------
 
-def _gradient_shardmap(grid: Grid, order, *,
-                       n_blocks: int = 1) -> GradientField:
-    """Lower-star gradient under ``shard_map``: each device owns a z-slab,
-    exchanges its boundary rank planes with ring neighbors (``ppermute``),
-    and runs the kernel on its own vertices — the gradient step of
-    ``repro.distributed.shardmap_pipeline.front_device_fn``.  The per-slab
-    kernel is the compiled fused Pallas kernel on a TPU and the XLA
-    ``jax`` kernel elsewhere."""
+def _shardmap_rows_fn(grid: Grid, n_blocks: int) -> Callable:
+    """orders (B, nv) -> the batch's :class:`GradientField`s and critical
+    counts, with the grid split into ``n_blocks`` z-slabs over a ring of
+    devices: one ``jax.jit(shard_map(...))`` per ``(dims, n_blocks)``,
+    bound through the plan cache like ``_rows_fn``.
+
+    The host casts the ranks to int32 and puts each slab on its device.
+    Each device exchanges its boundary rank planes with its ring
+    neighbours, runs the fused kernel on its halo-extended slab and
+    builds the fields of the sids based in its slab, the rows of its
+    last plane's simplices taken from the words of the next slab's
+    first plane (``shardmap_pipeline.slab_fields``).  The fields come
+    back sharded on the slab axis and the host cuts per-field views, as
+    on one chip.  A grid whose sids need int64 (``device_fields_fit``)
+    gathers the slabs' words and scatters them on the host instead.
+    Counters: ``n_blocks`` and ``halo_bytes``, the bytes a device sends
+    per field (two rank planes, and the two word planes of the ghost
+    plane)."""
     import jax
-    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.shardmap_pipeline import (FrontConfig,
-                                                     halo_gradient)
-    from repro.kernels.lower_star import interpret_mode
+    from repro.distributed.shardmap_pipeline import slab_program
+    from repro.kernels.lower_star import WORDS, device_fields_fit
 
-    n_dev = len(jax.devices())
-    if n_blocks > n_dev:
+    devices = jax.devices()
+    if n_blocks > len(devices):
         raise ValueError(
-            f"shardmap backend needs {n_blocks} devices, have {n_dev} "
-            "(set XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    cfg = FrontConfig(grid.dims, n_blocks,
-                      gradient_backend="jax" if interpret_mode() else "fused")
-    cfg.nz_local  # eager divisibility check
-    mesh = jax.make_mesh((n_blocks,), ("blocks",))
+            f"shardmap backend needs {n_blocks} devices, have "
+            f"{len(devices)} (set XLA_FLAGS="
+            "--xla_force_host_platform_device_count=N)")
+    if grid.nv >= 2 ** 31:
+        raise ValueError(f"the fused kernel takes int32 ranks; {grid.dims} "
+                         f"has {grid.nv} vertices")
+    on_device = device_fields_fit(grid)
+    mesh = jax.make_mesh((n_blocks,), ("blocks",)) \
+        if n_blocks == len(devices) \
+        else Mesh(np.asarray(devices[:n_blocks]), ("blocks",))
+    jfn = slab_program(grid, mesh, fields=on_device)
+    sharding = NamedSharding(mesh, P(None, "blocks"))
 
-    def dev_fn(o_slab):  # (nv_local,) int64 ranks of my slab
-        _, rows = halo_gradient(cfg, o_slab)
-        return rows
+    def put(orders):  # int32 ranks, each device's slab on the device
+        return jax.device_put(orders.astype(np.int32, copy=False),
+                              sharding)
 
-    fn = jax.shard_map(dev_fn, mesh=mesh, in_specs=P("blocks"),
-                       out_specs=P("blocks"), check_vma=False)
-    o = jnp.asarray(np.asarray(order).reshape(-1), jnp.int64)
-    status, partner, vstat, vpart = jax.jit(fn)(o)
-    return GR._scatter_results(grid, np.asarray(status), np.asarray(partner),
-                               np.asarray(vstat), np.asarray(vpart))
+    planes = 2 + (2 * WORDS if on_device else 0)
+    halo = 0 if n_blocks == 1 else planes * grid.dims[0] * grid.dims[1] * 4
+    return _rows_program(grid, jfn, put, on_device=on_device, words=True,
+                         n_blocks=n_blocks,
+                         counters=dict(n_blocks=n_blocks, halo_bytes=halo))
+
+
+def _gradient_shardmap(grid: Grid, order, *,
+                       n_blocks: int = 1) -> GradientField:
+    """One field through the cached shardmap rows program."""
+    from .plan import default_plan_cache
+    prog = default_plan_cache().get_or_build(
+        (grid.dims, "shardmap", n_blocks),
+        lambda: _shardmap_rows_fn(grid, n_blocks))
+    gfs, _ = prog(np.asarray(order).reshape(1, -1))
+    return gfs[0]
 
 
 # --------------------------------------------------------------------------
@@ -378,21 +425,24 @@ register_backend(Backend(
     name="jax", gradient=_make_kernel_gradient("jax"),
     caps=BackendCaps(jittable=True, batched=True, streamed=True),
     description="branchless masked-recomputation form, jit-compiled",
-    batched_rows=lambda grid: _rows_fn(grid, "jax")))
+    batched_rows=lambda grid, n_blocks=1: _rows_fn(grid, "jax")))
 
 register_backend(Backend(
     name="pallas", gradient=_make_kernel_gradient("pallas"),
     caps=BackendCaps(jittable=True, batched=True, fused=True),
     description="fused Pallas lower-star kernel (Mosaic on a TPU)",
-    batched_rows=lambda grid: _rows_fn(grid, "pallas")))
+    batched_rows=lambda grid, n_blocks=1: _rows_fn(grid, "pallas")))
 
 register_backend(Backend(
     name="pallas_prepass", gradient=_make_kernel_gradient("pallas_prepass"),
     caps=BackendCaps(jittable=True, batched=True),
     description="im2col pre-pass + vertex-tiled Pallas kernel (fallback)",
-    batched_rows=lambda grid: _rows_fn(grid, "pallas_prepass")))
+    batched_rows=lambda grid, n_blocks=1: _rows_fn(grid,
+                                                   "pallas_prepass")))
 
 register_backend(Backend(
     name="shardmap", gradient=_gradient_shardmap,
-    caps=BackendCaps(jittable=True, sharded=True),
-    description="shard_map z-slab front-end with ppermute halo exchange"))
+    caps=BackendCaps(jittable=True, sharded=True, batched=True, fused=True),
+    description="shard_map z-slab front-end: ppermute halo exchange, the "
+                "fused kernel and the fields on each device",
+    batched_rows=_shardmap_rows_fn))

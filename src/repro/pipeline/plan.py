@@ -271,7 +271,8 @@ class Plan:
                 registered = None
             if be is registered:
                 rows_program = cache.get_or_build(
-                    self.compile_key, lambda: be.batched_rows(grid))
+                    self.compile_key,
+                    lambda: be.batched_rows(grid, self.n_blocks))
             else:
                 # an unregistered (or shadowing same-named) Backend
                 # instance must never exchange compiled programs with
@@ -284,7 +285,8 @@ class Plan:
                         memo = {}
                         object.__setattr__(be, "_rows_memo", memo)
                     if self.compile_key not in memo:
-                        memo[self.compile_key] = be.batched_rows(grid)
+                        memo[self.compile_key] = be.batched_rows(
+                            grid, self.n_blocks)
                     rows_program = memo[self.compile_key]
         return Executable(plan=self, backend=be,
                           rows_program=rows_program, cache=cache)
@@ -295,10 +297,11 @@ class Executable:
     """A plan with its compiled artifacts bound, ready to execute.
 
     ``rows_program`` is the backend's jitted ``orders (B, nv) ->
-    (GradientFields, critical counts)`` program (None for non-batch
-    backends such as ``np`` / ``shardmap``): on the fused kernel it
-    builds the fields on the device, on the others it scatters packed
-    rows on the host (``backends._rows_fn``).  It comes out of the shared
+    (GradientFields, critical counts)`` program (None for the ``np``
+    backend): on the fused kernel, on one chip or slab by slab over a
+    ring (``shardmap``), it builds the fields on the device, on the
+    others it scatters packed rows on the host (``backends._rows_fn``,
+    ``backends._shardmap_rows_fn``).  It comes out of the shared
     :class:`PlanCache`, so repeated and batched requests of one ``(dims,
     backend, n_blocks)`` reuse a single compile."""
 

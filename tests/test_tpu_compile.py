@@ -21,7 +21,7 @@ HBM_BYTES = 16 * 2 ** 30        # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
@@ -34,8 +34,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, *shapes, sharding, hbm_bytes=HBM_BYTES):
@@ -88,3 +93,35 @@ def test_d0_round_compiles(one_chip):
     n_pad, m_pad = _bucket(50_000), _bucket(25_000)
     shapes = [((n_pad,), jnp.int64)] * 3 + [((m_pad,), jnp.int64)] * 3
     _compile(_d0_round(n_pad, m_pad), *shapes, sharding=one_chip)
+
+
+def test_shardmap_slab_program_compiles(topo, monkeypatch):
+    """The shardmap backend's program for one 128^3 volume in four
+    z-slabs on the four chips of a v5e 2x2: the ring exchanges as
+    collective-permutes, the fused kernel and the fields on each chip,
+    no XLA scatter, no (nv_local, 27) neighbour tensor, and a small
+    share of each chip's memory."""
+    import re
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+    from repro.core.grid import Grid
+    from repro.distributed.shardmap_pipeline import slab_program
+    from repro.kernels import lower_star
+    # the program asks the platform (the CPU here) whether to interpret
+    monkeypatch.setattr(lower_star, "interpret_mode", lambda: False)
+    assert len(topo.devices) == 4
+    mesh = Mesh(np.asarray(topo.devices), ("blocks",))
+    g = Grid.of(128, 128, 128)
+    arg = jax.ShapeDtypeStruct((1, g.nv), jnp.int32,
+                               sharding=NamedSharding(mesh, P(None, "blocks")))
+    compiled = slab_program(g, mesh).lower(arg).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
+    assert " scatter(" not in text
+    assert not re.search(r"\[[\d,]*,27\]", text)
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 0.5e9, f"{used:,} B a chip"
