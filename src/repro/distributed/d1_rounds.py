@@ -59,21 +59,6 @@ class D1Stats:
     addition_msgs: int = 0
 
 
-def edge_keys_packed(grid: Grid, order: np.ndarray) -> np.ndarray:
-    """Dense packed lexicographic key per edge sid: o_max * 2^31 + o_min.
-    Globally comparable without any rank exchange (the rank-free
-    'Extract & sort' optimization, see DESIGN.md)."""
-    space = grid.sid_space(1)
-    sids = np.arange(space, dtype=np.int64)
-    valid = np.asarray(grid.simplex_valid(1, sids))
-    keys = np.full(space, NEG_INF, dtype=np.int64)
-    vv = np.asarray(grid.simplex_vertices(1, sids[valid]))
-    o = order[vv]
-    keys[sids[valid]] = (np.maximum(o[:, 0], o[:, 1]) << 31) \
-        + np.minimum(o[:, 0], o[:, 1])
-    return keys
-
-
 class _Block:
     """Per-block state of the simulation (one MPI rank / TPU device)."""
 
@@ -127,11 +112,10 @@ def d1_distributed(grid: Grid, gf: GradientField, ci: CriticalInfo,
         import repro.core.grid as G
         return block_of_vertex(t // G.NTYPES[2])
 
-    # edge keys are compared, never decoded: the (o_max, o_min) packing
-    # needs orders < 2^31, and the dense edge ranks sort identically —
-    # use them for full-width rank-free key orders (streamed fronts)
-    ekey = edge_keys_packed(grid, ci.order) \
-        if int(np.max(ci.order)) < 2 ** 31 else ci.ranks[1]
+    # edge keys are compared, never decoded: the extraction's dense edge
+    # ranks (packed keys or lexsort ranks) sort like the lexicographic
+    # order, and only valid edges are ever read, all above NEG_INF
+    ekey = ci.ranks[1]
     trank = ci.ranks[2]
     c1_set = {int(x) for x in c1}
     n2 = len(c2)

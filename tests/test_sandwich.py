@@ -1,6 +1,7 @@
 """Sandwich back-end tests: np-reference vs jax-kernel parity across the
 field zoo x 2D/3D x asymmetric/thin grids x streamed sources, the
-positive-highest-edge invariant on a corrupted gradient, compile-count
+dense edge keys against the per-sid formulation, the distributed D1 on
+the extracted keys, the positive-highest-edge invariant on a corrupted gradient, compile-count
 regression for the bucketed D0 round, and the new StageReport timing
 split / `sandwich_backend` plumbing."""
 
@@ -13,12 +14,14 @@ from repro.core.pairing import ExtremaPairs, pair_extrema_saddles
 from repro.core.extremum_graph import ExtremumGraph
 from repro.fields.generators import FIELDS, make_field
 from repro.kernels.sandwich import (GradientInvariantError, TRACE_COUNTS,
+                                    _rank_compress, edge_keys_kernel,
                                     pair_extrema_saddles_kernel,
                                     pair_saddle_saddle_wavefront)
 from repro.pipeline import (PersistencePipeline, TopoRequest,
                             UnknownSandwichBackendError,
                             available_sandwich_backends,
                             get_sandwich_backend)
+from repro.stream.chunks import pack_value_keys
 
 
 def _run(field, dims, sandwich):
@@ -80,6 +83,85 @@ def test_parity_distributed_engines_on_kernel_extraction():
         pipe = PersistencePipeline("np", n_blocks=2, sandwich_backend=sb)
         res[sb] = pipe.run(TopoRequest(field=f, grid=Grid.of(*dims)))
     _assert_identical(res["np"], res["jax"], "distributed")
+
+
+# --------------------------------------------------------------------------
+# dense edge keys: shifted-slice build vs the per-sid formulation
+# --------------------------------------------------------------------------
+
+def _edge_keys_per_sid(grid, o):
+    """Oracle: decode every edge sid into its two vertices and pack the
+    gathered orders."""
+    space = grid.sid_space(1)
+    sids = np.arange(space, dtype=np.int64)
+    valid = np.asarray(grid.simplex_valid(1, sids))
+    keys = np.full(space, -1, dtype=np.int64)
+    ov = o[np.asarray(grid.simplex_vertices(1, sids[valid]))]
+    keys[sids[valid]] = (np.maximum(ov[:, 0], ov[:, 1]) << 31) \
+        + np.minimum(ov[:, 0], ov[:, 1])
+    return keys
+
+
+def _order_of(kind, grid, rng):
+    nv = grid.nv
+    if kind == "permutation":
+        return rng.permutation(nv).astype(np.int64)
+    if kind == "max31":
+        # distinct values up to the packing's limit, 2^31 - 1
+        step = 2 ** 31 // (nv + 1)
+        vals = (2 ** 31 - 1) - step * np.arange(nv, dtype=np.int64)
+        return rng.permutation(vals)
+    # streamed front: full-width packed (value, vid) keys, rank-compressed
+    # as the kernel extraction does before packing edge keys
+    f = rng.standard_normal(nv).astype(np.float32)
+    f[: nv // 2] = f[0]                     # ties resolved by the vid
+    packed = pack_value_keys(f, np.arange(nv, dtype=np.int64))
+    assert int(packed.max()) >= 2 ** 31
+    return _rank_compress(packed)
+
+
+@pytest.mark.parametrize("kind", ("permutation", "max31", "streamed"))
+@pytest.mark.parametrize("dims", GRIDS + [(7, 1, 1), (1, 1, 1), (17, 3, 2)])
+def test_edge_keys_match_per_sid_formulation(dims, kind):
+    grid = Grid.of(*dims)
+    o = _order_of(kind, grid, np.random.default_rng(sum(dims)))
+    if kind == "max31":
+        assert int(o.max()) == 2 ** 31 - 1
+    got = edge_keys_kernel(grid, o)
+    want = _edge_keys_per_sid(grid, o)
+    assert got.dtype == np.int64 and got.shape == want.shape
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("n_blocks", (2, 4))
+def test_distributed_d1_reads_extracted_edge_keys(n_blocks, monkeypatch):
+    # the token D1 pairs on the keys extraction built (one build per
+    # diagram) and matches the sequential reference pipeline
+    import repro.distributed.d1_rounds as d1r
+    import repro.kernels.sandwich as sw
+    assert not hasattr(d1r, "edge_keys_packed")
+    built, seen = [], []
+    build, d1 = sw.edge_keys_kernel, d1r.d1_distributed
+
+    def spy_build(grid, o):
+        built.append(build(grid, o))
+        return built[-1]
+
+    def spy_d1(grid, gf, ci, *args, **kw):
+        seen.append(ci.ranks[1])
+        return d1(grid, gf, ci, *args, **kw)
+
+    monkeypatch.setattr(sw, "edge_keys_kernel", spy_build)
+    monkeypatch.setattr(d1r, "d1_distributed", spy_d1)
+    dims = (8, 8, 8)
+    f = make_field("random", dims, seed=7)
+    req = TopoRequest(field=f, grid=Grid.of(*dims))
+    ref = PersistencePipeline("np", sandwich_backend="np").run(req)
+    out = PersistencePipeline("np", n_blocks=n_blocks,
+                              sandwich_backend="jax").run(req)
+    assert len(built) == 1 and len(seen) == 1 and seen[0] is built[0]
+    assert len(out.diagram.pairs[1]), "need at least one D1 pair"
+    _assert_identical(ref, out, ("d1_distributed", n_blocks))
 
 
 # --------------------------------------------------------------------------
